@@ -48,6 +48,7 @@ sys.path.insert(0, str(_ROOT))
 sys.path.insert(0, str(_ROOT / 'src'))      # `repro` without PYTHONPATH=src
 
 from benchmarks import tables as T
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -59,6 +60,7 @@ def main(argv=None) -> int:
     ap.add_argument('--json', metavar='OUT.json', default=None,
                     help='also write rows as structured JSON')
     args = ap.parse_args(argv)
+    enable_compile_cache()
     quick = not args.full
     want = set(args.tables.upper().split(','))
 

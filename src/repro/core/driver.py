@@ -33,7 +33,6 @@ from typing import Any, NamedTuple, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 WALKER_AXIS = 'walkers'
@@ -298,12 +297,12 @@ class EnsembleDriver:
                     f'{n_sh} shards')
         pop = Population(self.axis_name, n_sh)
         specs = self._state_specs(state)
-        inner = shard_map(
+        inner = jax.shard_map(
             lambda p, st, k: self._scan(p, st, k, pop),
             mesh=self.mesh,
             in_specs=(P(), specs, P()),
             out_specs=(specs, P()),     # BlockStats is fully reduced
-            check_rep=False)
+            check_vma=False)
         return jax.jit(inner, donate_argnums=donate)
 
     # -- sharding convention --------------------------------------------

@@ -11,7 +11,10 @@ Three implementations, all returning ``C: (n_orb, n_elec, 5)``:
   (8·k,128) tile blocking; bit-compared against these in tests.
 
 The five products share one A-gather (the paper's fused unroll-and-jam:
-amortize loads of A across the 5 right-hand sides).
+amortize loads of A across the 5 right-hand sides).  They run at full fp32
+(``Precision.HIGHEST``): their value rows are the Slater matrices whose
+fresh inverses the single-electron-move state must track to 1e-4
+(DESIGN.md §6), and a TPU's default for fp32 operands is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ import jax.numpy as jnp
 def mo_products_dense(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """A: (n_orb, n_ao), B: (n_ao, n_e, 5) -> C: (n_orb, n_e, 5)."""
     n_ao, n_e, five = B.shape
-    C = A @ B.reshape(n_ao, n_e * five)
+    C = jnp.matmul(A, B.reshape(n_ao, n_e * five), precision=jax.lax.Precision.HIGHEST)
     return C.reshape(A.shape[0], n_e, five)
 
 
@@ -71,7 +74,7 @@ def mo_products_sparse(A: jnp.ndarray, Bp: jnp.ndarray, idx: jnp.ndarray,
     def _body(carry, eb):
         bp, ix = eb                            # (chunk,K,5), (chunk,K)
         Ag = A[:, ix]                          # (n_orb, chunk, K)
-        c = jnp.einsum('oek,ekf->oef', Ag, bp,
+        c = jnp.einsum('oek,ekf->oef', Ag, bp, precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
         return carry, c
 
@@ -124,7 +127,7 @@ def mo_products_screened(A: jnp.ndarray, Bp: jnp.ndarray, idx: jnp.ndarray,
     def _body(carry, eb):
         bp, ix, m, ok = eb
         Asub = A[m[:, :, None], ix[:, None, :]]    # (chunk, K_mo, K_ao)
-        c = jnp.einsum('emk,ekf->emf', Asub, bp,
+        c = jnp.einsum('emk,ekf->emf', Asub, bp, precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
         return carry, jnp.where(ok[..., None], c, 0.0)
 

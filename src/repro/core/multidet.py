@@ -40,6 +40,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -214,7 +215,8 @@ def reference_table(C_vals: jnp.ndarray, Minv: jnp.ndarray) -> jnp.ndarray:
                            C_vals.shape[:-2] + (n_occ, n_occ))
     if C_vals.shape[-2] == n_occ:
         return eye
-    P_virt = jnp.einsum('...ve,...eh->...vh', C_vals[..., n_occ:, :], Minv)
+    P_virt = jnp.einsum('...ve,...eh->...vh', C_vals[..., n_occ:, :], Minv,
+                        precision=jax.lax.Precision.HIGHEST)
     return jnp.concatenate([eye, P_virt], axis=-2)
 
 

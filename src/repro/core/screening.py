@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -322,7 +323,7 @@ def gather_phi(A_blk: jnp.ndarray, ao_idx: jnp.ndarray, vals: jnp.ndarray,
     def _body(carry, wb):
         v, ix, m, ok = wb
         Asub = A_blk[m[:, :, None], ix[:, None, :]]       # (chunk, Kmo, Kao)
-        p = jnp.einsum('wmk,wk->wm', Asub, v,
+        p = jnp.einsum('wmk,wk->wm', Asub, v, precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
         return carry, jnp.where(ok, p, 0.0)
 
@@ -346,7 +347,7 @@ def phi_from_packed(A_blk: jnp.ndarray, ao_idx: jnp.ndarray,
     W = vals.shape[0]
     dense = jnp.zeros((W, n_ao), vals.dtype)
     dense = dense.at[jnp.arange(W)[:, None], ao_idx].add(vals, mode='drop')
-    return dense @ A_blk.T
+    return jnp.matmul(dense, A_blk.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def memory_budget(scr: Screening, basis: BasisSet, n_e: int, n_rows: int,

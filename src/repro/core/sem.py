@@ -67,6 +67,13 @@ from .wavefunction import (WavefunctionConfig, WavefunctionParams,
                            _ci_blocks, _mo_tensor_ensemble, _slater_blocks)
 
 
+# Products that feed the maintained inverses (proposal MO values, the
+# determinant ratio, the Sherman–Morrison column, the CI table update) run
+# at full fp32: a TPU's default for fp32 operands is one bf16 pass, far
+# short of the 1e-4 drift contract against a fresh recompute (DESIGN.md §6).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 class SEMEnsemble(NamedTuple):
     """Walker-major single-electron-move state (driver-sharded leading axis).
 
@@ -328,9 +335,10 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
                                                 cfg.basis.n_ao)
         else:
             vals, _ = aos.eval_ao_values(cfg.basis, coords, r_new)  # (ao,W)
-            v_all = (A_blk @ vals).T             # (W, n_occ | n_orb)
+            v_all = jnp.matmul(A_blk, vals, precision=_HIGHEST).T
         phi = v_all[:, :minv.shape[-1]]          # occupied panel
-        ratio = jnp.einsum('wo,wo->w', minv[:, e, :], phi)
+        ratio = jnp.einsum('wo,wo->w', minv[:, e, :], phi,
+                           precision=_HIGHEST)
         d_jas = jax.vmap(
             lambda rw, rn: jastrow_delta_one_electron(
                 params.jastrow, rw, j, rn, coords, charges, cfg.n_up)
@@ -340,7 +348,8 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
             # CI factor: all excitation ratios off the rank-1-updated
             # table (un-guarded 1/ratio: a near-node reference move makes
             # the comparison NaN -> rejected, like the log barrier)
-            g_vec = jnp.einsum('woh,wh->wo', P, phi) - v_all
+            g_vec = jnp.einsum('woh,wh->wo', P, phi,
+                               precision=_HIGHEST) - v_all
             row_t = minv[:, e, :] / ratio[:, None]
             rdet_new, S_new = _move_ci_ratios(cfg, P, g_vec, row_t,
                                               holes, parts, r_other)
@@ -362,7 +371,8 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
             # the guarded ``row`` below exact on every ACCEPTED walker.
             accept = accept & (jnp.abs(ratio) > 1e-20)
 
-        u_vec = jnp.einsum('weo,wo->we', minv, phi)       # (W, n_blk)
+        u_vec = jnp.einsum('weo,wo->we', minv, phi,
+                           precision=_HIGHEST)            # (W, n_blk)
         safe = jnp.where(jnp.abs(ratio) > 1e-20, ratio, 1.0)
         row = minv[:, e, :] / safe[:, None]
         minv = _apply_update(cfg, minv, u_vec, row, accept, e)
@@ -401,7 +411,7 @@ def _fused_phi_block(cfg, params, A_blk, pts):
         return scr_mod.phi_from_packed(A_blk, a_idx, vals_p,
                                        cfg.basis.n_ao)
     vals, _ = aos.eval_ao_values(cfg.basis, params.coords, pts)  # (ao, N)
-    return (A_blk @ vals).T
+    return jnp.matmul(A_blk, vals, precision=_HIGHEST).T
 
 
 def _fused_phi_all(cfg, params, A_up, A_dn, r_prop):
@@ -451,17 +461,21 @@ def _fused_phi_all(cfg, params, A_up, A_dn, r_prop):
                 phi_dn.reshape(W, n_dn, -1) if phi_dn is not None else None)
     vals, _ = aos.eval_ao_values(cfg.basis, params.coords, pts)  # (ao, N)
     if n_dn == 0:
-        return (A_up @ vals).T.reshape(W, n_up, -1), None
+        return jnp.matmul(A_up, vals, precision=_HIGHEST).T.reshape(
+            W, n_up, -1), None
     if (A_up.shape == A_dn.shape
             and (A_up is A_dn or A_up.shape[0] == cfg.n_up == cfg.n_dn
                  and cfg.shared_orbitals)):
         # closed shell / CI: one panel serves both blocks -> ONE GEMM in
         # the AO-major layout, split afterwards
-        phi = (A_up @ vals).T.reshape(W, n_e, -1)
+        phi = jnp.matmul(A_up, vals, precision=_HIGHEST).T.reshape(
+            W, n_e, -1)
         return phi[:, :n_up], phi[:, n_up:]
     chi = vals.T.reshape(W, n_e, -1)
-    phi_up = jnp.einsum('wea,oa->weo', chi[:, :n_up], A_up)
-    phi_dn = jnp.einsum('wea,oa->weo', chi[:, n_up:], A_dn)
+    phi_up = jnp.einsum('wea,oa->weo', chi[:, :n_up], A_up,
+                        precision=_HIGHEST)
+    phi_dn = jnp.einsum('wea,oa->weo', chi[:, n_up:], A_dn,
+                        precision=_HIGHEST)
     return phi_up, phi_dn
 
 
@@ -633,10 +647,19 @@ class SEMVMCPropagator:
             return mu, minv_dn, su, lu
 
         def _correct(_):
+            # sign and log|det| come from a batched LU of the sweep's
+            # Slater matrices, not from the sum of the moves' log-ratios:
+            # at |log det| ~ 600 (the paper's 158 electrons) one fp32 ulp is
+            # 6e-5, and ~100 accepted moves per sweep drift that sum past
+            # the 1e-4 contract (DESIGN.md §6).  The LU costs about what
+            # the two Newton–Schulz GEMMs do.
             mu = slater.refine_inverse(up[..., 0], minv_up)
-            md = (slater.refine_inverse(dn[..., 0], minv_dn)
-                  if cfg.n_dn > 0 else minv_dn)
-            return mu, md, sign, logdet
+            su, lu = jnp.linalg.slogdet(up[..., 0])
+            if cfg.n_dn > 0:
+                md = slater.refine_inverse(dn[..., 0], minv_dn)
+                sd, ld = jnp.linalg.slogdet(dn[..., 0])
+                return mu, md, su * sd, lu + ld
+            return mu, minv_dn, su, lu
 
         minv_up, minv_dn, sign, logdet = jax.lax.cond(
             sweeps % cfg.sem_refresh == 0, _refresh, _correct, None)

@@ -27,10 +27,16 @@ class SlaterState(NamedTuple):
 
 
 def refine_inverse(D: jnp.ndarray, X: jnp.ndarray, steps: int = 1):
-    """Newton–Schulz: X <- X (2I - D X); quadratic convergence."""
+    """Newton–Schulz: X <- X (2I - D X); quadratic convergence.
+
+    Both products run at full fp32 (the TPU's default for fp32 operands is
+    one bf16 pass, far short of the 1e-4 drift contract of DESIGN.md §6).
+    """
+    hi = jax.lax.Precision.HIGHEST
     eye2 = 2.0 * jnp.eye(D.shape[-1], dtype=D.dtype)
     for _ in range(steps):
-        X = X @ (eye2 - D @ X)
+        X = jnp.matmul(X, eye2 - jnp.matmul(D, X, precision=hi),
+                       precision=hi)
     return X
 
 

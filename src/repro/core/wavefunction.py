@@ -49,13 +49,13 @@ class WavefunctionConfig:
     #                                dense/kernel energy pass survives the
     #                                propagator rewrite.
     ns_steps: int = 1              # Newton–Schulz refinement of the inverse
-    kernel_tiles: tuple = (8, 8, 8)  # (tile_o, tile_k, tile_e); 128s on TPU
+    kernel_tiles: tuple = (8, 8, 8)  # (tile_o, tile_k, tile_e) in interpret
+    #                                mode; kernels.platform picks the TPU's
     ensemble_eval: bool = True     # VMC/DMC walker batches: one flattened
     #                                AO->MO->Slater pass instead of per-walker
     #                                vmap (DESIGN.md §4)
-    kernel_ensemble_tile_cap: int = 0  # tile_e cap for ensemble kernel
-    #                                calls; 0 -> auto per backend (128 on
-    #                                TPU, 2048 on CPU/interpret — see
+    kernel_ensemble_tile_cap: int = 0  # interpret-mode tile_e cap for
+    #                                ensemble kernel calls; 0 -> 2048 (see
     #                                kernels.sparse_mo.ops.ensemble_tiles)
     sem_refresh: int = 8           # single-electron-move propagator: full
     #                                slater_state recompute every this many
@@ -254,6 +254,7 @@ def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
         return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
     if _mo_product_method(cfg) == 'dense' or cfg.k_max <= 0:
         Cw = jnp.einsum('oa,waec->woec', params.mo, Bw,
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
         return Cw, count
     idx, valid, _ = aos.active_ao_indices(

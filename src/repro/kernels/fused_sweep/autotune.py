@@ -11,7 +11,8 @@ persists the winner in a small JSON cache keyed on
     {"schema": 1, "tiles": {"60|256|fp32|cpu": 16, ...}}
 
 Cache location: ``$REPRO_FUSED_TILE_CACHE`` or
-``~/.cache/repro/fused_sweep_tiles.json``.  A cache hit returns the stored
+``.tile_cache/fused_sweep_tiles.json`` in the checkout
+(``repro.compile_cache``).  A cache hit returns the stored
 tile without re-measuring (``build_count()`` exposes the number of
 measurement runs so tests can pin determinism); a corrupt, stale-schema or
 otherwise unreadable cache falls back to re-measuring and rewrites the
@@ -40,7 +41,8 @@ def cache_path() -> Path:
     env = os.environ.get('REPRO_FUSED_TILE_CACHE')
     if env:
         return Path(env)
-    return Path.home() / '.cache' / 'repro' / 'fused_sweep_tiles.json'
+    from repro.compile_cache import TILE_CACHE_DIR
+    return TILE_CACHE_DIR / 'fused_sweep_tiles.json'
 
 
 def _cache_key(n_e: int, W: int, dtype: str, backend: str) -> str:
@@ -75,6 +77,8 @@ def _measure(n_e: int, W: int, candidates, repeats: int = 2) -> int:
 
     Single-determinant, n_up = ceil(n_e/2), random fp32 state — the shapes
     are what matters; min-of-N wall time per candidate, smallest wins.
+    Each candidate runs the way ``kernels.platform.kernel_plan`` picks for
+    this platform: compiled on a TPU, interpreted elsewhere.
     """
     import jax
     import jax.numpy as jnp
@@ -100,7 +104,7 @@ def _measure(n_e: int, W: int, candidates, repeats: int = 2) -> int:
             out = fused_sweep_block(
                 minv, phi, r, r_prop, en, logu, sign, logdet,
                 jnp.float32(1.0), offset=0, n_up=n_up, use_kernel=True,
-                tile_w=tile_w, interpret=True)
+                tile_w=tile_w)
             jax.block_until_ready(out)
         _run()                                       # compile/warmup
         t = min(_timed(_run) for _ in range(repeats))

@@ -19,8 +19,9 @@ the SAME ``ref._move_step`` math as the scan oracle — kernel-vs-ref
 parity is bitwise by construction.  Excitation lists / CI coefficients /
 the e-e Padé denominator are tiny replicated operands (every grid step
 maps block 0).  Walker tiles are independent, so the grid dimension is
-declared ``parallel`` on real TPU; ``interpret=True`` (the repo's CPU
-validation default) has no tiling constraints.
+declared ``parallel`` on the TPU; ``kernels.platform.kernel_plan``
+decides interpret mode, and refuses widths whose walker tile of the
+inverse cannot fit VMEM.
 
 ``tile_w`` is chosen by the measured autotuner (``autotune.best_tile_w``,
 persisted per (n_e, W, dtype, backend)).
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import _move_step
+from .ref import _move_step, take
 
 
 def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
@@ -52,12 +53,11 @@ def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
         (minv_ref, phi_ref, r_ref, rp_ref, en_ref, logu_ref, sign_ref,
          logdet_ref, bee_ref,
          minv_out, r_out, sign_out, logdet_out, acc_out) = refs
-        ci_args = None
-        tw = minv_ref.shape[0]
-        P0 = jnp.zeros((tw, 0, 0), minv_ref.dtype)
-        rdet0 = jnp.zeros((tw, 0), minv_ref.dtype)
+        # no CI state: None leaves keep zero-size vectors out of the body
+        ci_args = P0 = rdet0 = None
 
     b_ee = bee_ref[0, 0]
+    sign0, logdet0 = sign_ref[...][:, 0], logdet_ref[...][:, 0]
     phi = phi_ref[...]                      # (tw, n_blk, n_cols)
     rp = rp_ref[...]                        # (tw, n_blk, 3)
     en = en_ref[...]                        # (tw, n_blk)
@@ -68,21 +68,21 @@ def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
     def _step(e, carry):
         state, acc = carry
         state, accept = _move_step(
-            state, e, phi[:, e], rp[:, e], en[:, e], logu[:, e], b_ee,
+            state, e, take(phi, e), take(rp, e), take(en, e),
+            take(logu, e), b_ee,
             offset=offset, n_up=n_up, n_occ=n_occ, n_e_valid=n_e_valid,
             ci_args=ci_args)
         move = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
         acc = jnp.where(move == e, accept[:, None].astype(acc.dtype), acc)
         return state, acc
 
-    state0 = (r_ref[...], minv_ref[...], sign_ref[...], logdet_ref[...],
-              P0, rdet0)
+    state0 = (r_ref[...], minv_ref[...], sign0, logdet0, P0, rdet0)
     (r, minv, sign, logdet, P, rdet), acc = jax.lax.fori_loop(
         0, n_blk, _step, (state0, acc0))
     minv_out[...] = minv
     r_out[...] = r
-    sign_out[...] = sign
-    logdet_out[...] = logdet
+    sign_out[...] = sign[:, None]
+    logdet_out[...] = logdet[:, None]
     acc_out[...] = acc
     if multidet:
         p_out[...] = P
@@ -94,19 +94,22 @@ def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
                                              'interpret'))
 def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
                      b_ee, ci_ops=None, *, offset, n_up, n_occ, n_e_valid,
-                     tile_w=8, interpret=True):
+                     tile_w=8, interpret):
     """Raw kernel dispatch on pre-padded walker-major operands.
 
     Args:
       minv: (W, n, n) f32, W a multiple of ``tile_w``.
       phi: (W, n_blk, n_cols); r: (W, n_e, 3); r_prop: (W, n_blk, 3);
-      en_delta/logu: (W, n_blk); sign/logdet: (W,); b_ee: (1, 1).
+      en_delta/logu: (W, n_blk); sign/logdet: (W, 1); b_ee: (1, 1).
       ci_ops: None or (P (W, n_orb, n_occ), rdet (W, n_det),
         r_other (W, n_det), holes (n_det, k) i32, parts, coeffs (n_det,)).
       offset/n_up/n_occ/n_e_valid: static block geometry (true sizes under
         lane padding — see ``ops.fused_sweep_block``).
+      interpret: run the Python interpreter backend (off the TPU; see
+        ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
-    Returns (minv, r, sign, logdet, acc (W, n_blk) f32[, P, rdet]).
+    Returns (minv, r, sign (W, 1), logdet (W, 1), acc (W, n_blk) f32
+    [, P, rdet]).
     """
     W, n, _ = minv.shape
     n_e = r.shape[1]
@@ -122,12 +125,12 @@ def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
         return pl.BlockSpec(tuple(block), lambda w: (0,) * len(block))
 
     in_specs = [_w(n, n), _w(n_blk, n_cols), _w(n_e, 3), _w(n_blk, 3),
-                _w(n_blk), _w(n_blk), _w(), _w(), _rep(1, 1)]
-    out_specs = [_w(n, n), _w(n_e, 3), _w(), _w(), _w(n_blk)]
+                _w(n_blk), _w(n_blk), _w(1), _w(1), _rep(1, 1)]
+    out_specs = [_w(n, n), _w(n_e, 3), _w(1), _w(1), _w(n_blk)]
     out_shape = [jax.ShapeDtypeStruct((W, n, n), minv.dtype),
                  jax.ShapeDtypeStruct((W, n_e, 3), r.dtype),
-                 jax.ShapeDtypeStruct((W,), sign.dtype),
-                 jax.ShapeDtypeStruct((W,), logdet.dtype),
+                 jax.ShapeDtypeStruct((W, 1), sign.dtype),
+                 jax.ShapeDtypeStruct((W, 1), logdet.dtype),
                  jax.ShapeDtypeStruct((W, n_blk), jnp.float32)]
     operands = [minv, phi, r, r_prop, en_delta, logu, sign, logdet,
                 jnp.asarray(b_ee, jnp.float32).reshape(1, 1)]
@@ -148,7 +151,7 @@ def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
     kwargs = {}
     if not interpret:
         # walker tiles write disjoint output blocks: fully parallel
-        kwargs['compiler_params'] = pltpu.TPUCompilerParams(
+        kwargs['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel',))
     body = functools.partial(_body, n_blk=n_blk, offset=offset, n_up=n_up,
                              n_occ=n_occ, n_e_valid=n_e_valid,

@@ -5,13 +5,13 @@ spin block.  ``use_kernel=False`` (cfg.method == 'fused') runs the
 ``lax.scan`` oracle directly; ``use_kernel=True`` (cfg.method ==
 'fused-kernel') pads the walker axis to the autotuned ``tile_w``
 (padded walkers carry ``logu = +1e30`` so they never accept and pass
-through untouched), pads the matrix/electron lanes to the f32 VMEM tile
-on real TPU (interpret mode has no tiling constraint and skips the
-blow-up), dispatches ``kernel.fused_sweep_call`` and slices back.
+through untouched), pads the matrix lanes to the f32 VMEM tile on the TPU
+(interpret mode has no tiling constraint and skips the blow-up),
+dispatches ``kernel.fused_sweep_call`` and slices back.
 
 The multidet path keeps its table dimensions unpadded (the CI gathers
-index true orbital rows); it is validated under interpret mode like the
-rest of the repo's kernels.
+index true orbital rows); it runs in interpret mode only and is refused
+on the TPU.
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..platform import kernel_plan
 from .kernel import fused_sweep_call
 from .ref import fused_sweep_ref
 
@@ -33,11 +34,9 @@ def _pad_axis(x: jnp.ndarray, axis: int, mult: int, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=('offset', 'n_up', 'use_kernel',
-                                             'tile_w', 'interpret'))
 def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
                       b_ee, ci_ops=None, *, offset, n_up, use_kernel=False,
-                      tile_w=8, interpret=True):
+                      tile_w=8):
     """One spin block's fused sweep: scan oracle or Pallas kernel.
 
     Args:
@@ -47,37 +46,56 @@ def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
       r_prop: (W, n_blk, 3); en_delta/logu: (W, n_blk); sign/logdet: (W,).
       b_ee: () e-e Padé denominator.
       ci_ops: None or (P, rdet, r_other, holes, parts, coeffs).
-      offset/n_up: static block geometry; use_kernel/tile_w/interpret:
-        static dispatch knobs.
+      offset/n_up: static block geometry; use_kernel/tile_w: static
+        dispatch knobs.  ``kernels.platform.kernel_plan`` decides interpret
+        mode and, on the TPU, shrinks ``tile_w`` to fit VMEM or refuses the
+        width.
 
     Returns (r, minv, sign, logdet, P, rdet, accept) with accept a
     (W, n_blk) bool matrix (move-for-move Metropolis outcomes).
     """
-    W, n, _ = minv.shape
     if not use_kernel:
-        P = rdet = None
-        ci_args = None
-        if ci_ops is not None:
-            P, rdet, r_other, holes, parts, coeffs = ci_ops
-            ci_args = (jnp.asarray(holes, jnp.int32),
-                       jnp.asarray(parts, jnp.int32),
-                       jnp.asarray(coeffs, jnp.float32), r_other)
-        (r, minv, sign, logdet, P, rdet), acc = fused_sweep_ref(
-            r, minv, sign, logdet, phi, r_prop, en_delta, logu, b_ee,
-            offset=offset, n_up=n_up, P=P, rdet=rdet, ci_args=ci_args)
-        return r, minv, sign, logdet, P, rdet, acc
+        return _ref_block(minv, phi, r, r_prop, en_delta, logu, sign,
+                          logdet, b_ee, ci_ops, offset=offset, n_up=n_up)
+    n_det = 0 if ci_ops is None else ci_ops[1].shape[-1]
+    plan = kernel_plan('fused_sweep', (tile_w,), n=minv.shape[-1],
+                       n_e=r.shape[1], n_det=n_det)
+    return _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
+                         b_ee, ci_ops, offset=offset, n_up=n_up,
+                         tile_w=plan.tiles[0], interpret=plan.interpret)
 
-    n_e, n_blk, n_cols = r.shape[1], phi.shape[1], phi.shape[2]
-    # real TPU wants the trailing two block dims on the (8, 128) f32 tile;
-    # interpret mode has no constraint — pad only the walker axis there.
-    # CI table gathers index true orbital rows, so the multidet path stays
-    # lane-unpadded (interpret-validated, like multidet_ratio).
-    lane = 128 if (not interpret and ci_ops is None) else 1
+
+@functools.partial(jax.jit, static_argnames=('offset', 'n_up'))
+def _ref_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
+               ci_ops, *, offset, n_up):
+    P = rdet = None
+    ci_args = None
+    if ci_ops is not None:
+        P, rdet, r_other, holes, parts, coeffs = ci_ops
+        ci_args = (jnp.asarray(holes, jnp.int32),
+                   jnp.asarray(parts, jnp.int32),
+                   jnp.asarray(coeffs, jnp.float32), r_other)
+    (r, minv, sign, logdet, P, rdet), acc = fused_sweep_ref(
+        r, minv, sign, logdet, phi, r_prop, en_delta, logu, b_ee,
+        offset=offset, n_up=n_up, P=P, rdet=rdet, ci_args=ci_args)
+    return r, minv, sign, logdet, P, rdet, acc
+
+
+@functools.partial(jax.jit, static_argnames=('offset', 'n_up', 'tile_w',
+                                             'interpret'))
+def _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
+                  ci_ops, *, offset, n_up, tile_w, interpret):
+    W, n, _ = minv.shape
+    n_e = r.shape[1]
+    # the TPU wants the trailing two block dims of the inverse and the
+    # proposal panel on the (8, 128) f32 tile; interpret mode has no
+    # constraint and pads only the walker axis.  Positions keep their
+    # full (n_e, 3) trailing dims, which the tiling rule also accepts.
+    lane = 1 if interpret else 128
     minv_p = _pad_axis(_pad_axis(minv, 1, lane), 2, lane)
-    phi_p = _pad_axis(_pad_axis(phi, 1, 1), 2, lane)
-    r_p = _pad_axis(_pad_axis(r, 1, lane), 2, lane)
-    rp_p = _pad_axis(r_prop, 2, lane)
-    args = [minv_p, phi_p, r_p, rp_p, en_delta, logu, sign, logdet]
+    phi_p = _pad_axis(phi, 2, lane)
+    args = [minv_p, phi_p, r, r_prop, en_delta, logu, sign[:, None],
+            logdet[:, None]]
     args = [_pad_axis(a, 0, tile_w) for a in args]
     # padded walkers must never accept: +1e30 beats any finite log-ratio
     args[5] = _pad_axis(logu, 0, tile_w, value=1e30)
@@ -97,8 +115,8 @@ def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
         minv_o, r_o, sign_o, logdet_o, acc = out
         P_o = jnp.zeros((W, 0, 0), minv.dtype)
         rdet_o = jnp.zeros((W, 0), minv.dtype)
-    return (r_o[:W, :n_e, :3], minv_o[:W, :n, :n], sign_o[:W],
-            logdet_o[:W], P_o, rdet_o, acc[:W].astype(bool))
+    return (r_o[:W], minv_o[:W, :n, :n], sign_o[:W, 0], logdet_o[:W, 0],
+            P_o, rdet_o, acc[:W].astype(bool))
 
 
 __all__ = ['fused_sweep_block', 'fused_sweep_ref']

@@ -29,6 +29,11 @@ import jax.numpy as jnp
 
 from repro.kernels.multidet_ratio.ref import multidet_ratios_ref
 
+# the ratio and Sherman–Morrison products feed the maintained inverse,
+# which must track a fresh recompute to 1e-4 (DESIGN.md §6): full fp32
+# on the TPU's MXU, whose default for fp32 operands is one bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _pade_u(r, a, b):
     """Padé value u = a r / (1 + b r) (``core.jastrow._pade`` value part)."""
@@ -47,15 +52,25 @@ def _ee_sum(r, j, point, n_up, b_ee, n_e_valid):
     r: (W, n_e, 3); point: (W, 3); j: traced electron index.
     Returns (W,).
     """
-    n_e = r.shape[-2]
     d = point[:, None, :] - r                             # (W, n_e, 3)
     dist = jnp.sqrt(jnp.sum(d * d, axis=-1) + 1e-20)
-    i = jnp.arange(n_e)
+    i = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
     a = jnp.where((i < n_up) == (j < n_up), jnp.asarray(0.25, r.dtype),
                   jnp.asarray(0.5, r.dtype))
     u = _pade_u(dist, a, b_ee)
     keep = ((i != j) & (i < n_e_valid)).astype(r.dtype)
     return jnp.sum(u * keep, axis=-1)
+
+
+def take(x, i, axis=1):
+    """``x`` at index ``i`` of ``axis`` (traced ``i``), as a masked sum.
+
+    The TPU kernel body cannot slice a value at a traced index; selecting
+    with an iota mask and summing adds only exact zeros, so the result is
+    the slice itself, bit for bit.
+    """
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) == i
+    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=axis)
 
 
 def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
@@ -69,7 +84,7 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
 
     Args:
       state: (r, minv, sign, logdet, P, rdet) — P/rdet are zero-size
-        arrays in the single-determinant case.
+        arrays (scan) or None (kernel) in the single-determinant case.
       e: block-local electron index (traced).
       phi_e: (W, n_cols) proposal MO values (occupied panel = [:, :n_occ];
         full orbital panel with ``ci_args``).
@@ -85,17 +100,22 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
     """
     r, minv, sign, logdet, P, rdet = state
     j = offset + e
-    r_old = r[:, j]                                       # (W, 3)
-    phi = phi_e[:, :n_occ]
-    ratio = jnp.einsum('wo,wo->w', minv[:, e, :n_occ], phi)
+    r_old = take(r, j)                                    # (W, 3)
+    # lanes past n_occ are the zero padding of minv and phi (the CI panel's
+    # virtual orbitals are sliced off), so full-lane sums are exact
+    phi = phi_e[:, :minv.shape[-1]]
+    minv_e = take(minv, e)                                # (W, n_lanes)
+    ratio = jnp.einsum('wxo,wyo->wxy', minv_e[:, None], phi[:, None],
+                       precision=_HIGHEST)[:, 0, 0]
     ee_new = _ee_sum(r, j, rp_e, n_up, b_ee, n_e_valid)
     ee_old = _ee_sum(r, j, r_old, n_up, b_ee, n_e_valid)
     d_jas = ee_new - ee_old + en_e
     log_ratio = jnp.log(jnp.abs(ratio) + 1e-30)
     if ci_args is not None:
         holes, parts, coeffs, r_other = ci_args
-        g_vec = jnp.einsum('woh,wh->wo', P, phi) - phi_e
-        row_t = minv[:, e, :n_occ] / ratio[:, None]
+        g_vec = jnp.einsum('woh,wh->wo', P, phi,
+                           precision=_HIGHEST) - phi_e
+        row_t = minv_e[:, :n_occ] / ratio[:, None]
         rdet_new, S_new = multidet_ratios_ref(P, g_vec, row_t, holes,
                                               parts, coeffs, r_other)
         S_old = jnp.einsum('d,wd,wd->w', coeffs, rdet, r_other)
@@ -108,9 +128,10 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
         # near-reference-node guard — see core.sem._sweep_spin_block
         accept = accept & (jnp.abs(ratio) > 1e-20)
 
-    u_vec = jnp.einsum('weo,wo->we', minv[..., :n_occ], phi)  # (W, n_blk)
+    u_vec = jnp.einsum('weo,wo->we', minv, phi,
+                       precision=_HIGHEST)                # (W, n_rows)
     safe = jnp.where(jnp.abs(ratio) > 1e-20, ratio, 1.0)
-    row = minv[:, e, :] / safe[:, None]
+    row = minv_e / safe[:, None]
     # rank-1 update + row replacement via iota select (kernel-safe store)
     upd = minv - u_vec[:, :, None] * row[:, None, :]
     elec = jax.lax.broadcasted_iota(jnp.int32, upd.shape, 1)

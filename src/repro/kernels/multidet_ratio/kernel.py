@@ -59,7 +59,7 @@ def _kernel(planes_ref, ro_ref, c_ref, out_r_ref, out_s_ref):
                                              'interpret'))
 def multidet_ratio_matmul(planes: jnp.ndarray, r_other: jnp.ndarray,
                           coeffs: jnp.ndarray, *, tile_w: int = 8,
-                          tile_d: int = 128, interpret: bool = True):
+                          tile_d: int = 128, interpret: bool):
     """Raw kernel dispatch on pre-gathered, pre-padded plane stacks.
 
     Args:
@@ -67,8 +67,8 @@ def multidet_ratio_matmul(planes: jnp.ndarray, r_other: jnp.ndarray,
         ``tile_d`` (padded dets carry zero planes and zero coeffs).
       r_other: (W, n_det) f32 other-spin ratios.
       coeffs: (1, n_det) f32 CI coefficients.
-      interpret: Python interpreter backend (CPU validation); False
-        targets real TPU hardware.
+      interpret: run the Python interpreter backend (off the TPU; see
+        ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
     Returns (ratios (W, n_det), sums (W, 128)) — per-determinant ratios
     and the CI partial sums accumulated into lane 0.
@@ -79,7 +79,7 @@ def multidet_ratio_matmul(planes: jnp.ndarray, r_other: jnp.ndarray,
     kwargs = {}
     if not interpret:
         # walker tiles are independent; det tiles accumulate sequentially
-        kwargs['compiler_params'] = pltpu.TPUCompilerParams(
+        kwargs['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary'))
     return pl.pallas_call(
         _kernel,

@@ -9,6 +9,7 @@ import numpy as np
 
 from repro.core import multidet
 
+from ..platform import kernel_plan
 from .kernel import multidet_ratio_matmul
 from .ref import multidet_ratios_ref
 
@@ -75,8 +76,7 @@ def _dispatch(P, g, row, holes, parts, coeffs, r_other, tile_w, tile_d,
 
 def multidet_ratios(P: jnp.ndarray, g: jnp.ndarray, row: jnp.ndarray,
                     holes, parts, coeffs, r_other: jnp.ndarray, *,
-                    tile_w: int = 8, tile_d: int = 128,
-                    interpret: bool = True):
+                    tile_w: int = 8, tile_d: int = 128):
     """Batched multideterminant move ratios + CI sum (kernel dispatch).
 
     Kernel-dispatching equivalent of ``ref.multidet_ratios_ref`` (same
@@ -87,14 +87,18 @@ def multidet_ratios(P: jnp.ndarray, g: jnp.ndarray, row: jnp.ndarray,
     ``tile_w`` and the determinant axis to ``tile_d`` (padded dets carry
     zero planes AND zero coefficients, so they contribute exact zeros),
     runs ``kernel.multidet_ratio_matmul``, and slices back.
+    ``kernels.platform.kernel_plan`` decides interpret mode and, on the
+    TPU, the (8, 128) tiles (``tile_w``/``tile_d`` apply when
+    interpreting).
 
     Returns (ratios (W, n_det), ci (W,)).
     """
     n_occ, n_orb = P.shape[-1], P.shape[-2]
     holes, parts = normalized_excitations(holes, parts, n_occ, n_orb)
+    plan = kernel_plan('multidet_ratio', (tile_w, tile_d))
     return _dispatch(P, g, row,
                      tuple(map(tuple, holes)), tuple(map(tuple, parts)),
-                     coeffs, r_other, tile_w, tile_d, interpret)
+                     coeffs, r_other, *plan.tiles, plan.interpret)
 
 
 __all__ = ['multidet_ratios', 'multidet_ratios_ref',

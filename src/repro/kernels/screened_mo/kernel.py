@@ -7,20 +7,23 @@ of candidate AO ids (``idx``) and packed values (``Bp``), so the kernel
 only ever touches active (electron, AO) pairs — the memory-minimal layout
 of the paper's idea ii.).
 
-Per grid step the kernel holds a resident (tile_o, n_ao) panel of A (A
-stays dense — the paper's key choice), gathers the candidate columns of an
-electron tile's k-chunk, and accumulates the five right-hand sides in one
-batched contraction:
+Per grid step the kernel holds a resident (n_ao, tile_o) panel of A^T
+(A stays dense — the paper's key choice; transposed so that one AO's
+orbital coefficients are one row), and for each electron of the tile
+walks its k-chunk of candidates: the candidate id and its five packed
+values are scalars read from SMEM, the A^T row is a dynamic one-row load,
+and the five right-hand sides accumulate on the VPU in fp32:
 
-    C[o_tile, e] += A[o_tile, idx[e, kc]] @ Bp[e, kc]      for all e in tile
+    C^T[e*5 + f, o_tile] += sum_k Bp[e, k, f] * A^T[idx[e, k], o_tile]
 
-A scalar-prefetched per-(electron-tile, k-chunk) activity mask skips chunks
-whose candidates are all inactive (``pl.when``), which is where ragged
-active counts win back time.  Grid: (e_tiles, o_tiles, k_chunks) with k
-innermost so the C tile stays resident across the accumulation; e/o are
-``parallel``, k ``arbitrary`` on real TPU.  ``interpret=True`` (the CI
-default) runs the Python backend on CPU; the in-kernel gather lowers to
-Mosaic's dynamic-gather path on real TPU.
+The output is electron-major, ``(n_e * 5, n_orb)``, so an 8-electron tile
+fills a (40, tile_o) block: 40 sublanes and 128 lanes, which the TPU's
+(8, 128) tiling accepts.  A scalar-prefetched per-(electron-tile, k-chunk)
+activity mask skips chunks whose candidates are all inactive
+(``pl.when``), which is where ragged active counts win back time.  Grid:
+(e_tiles, o_tiles, k_chunks) with k innermost so the C tile stays resident
+across the accumulation; e/o are ``parallel``, k ``arbitrary`` on the TPU.
+``kernels.platform.kernel_plan`` decides interpret mode and tiles.
 """
 from __future__ import annotations
 
@@ -32,50 +35,54 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(chunk_any_ref, a_ref, idx_ref, b_ref, c_ref):
-    e = pl.program_id(0)
+def _kernel(chunk_any_ref, at_ref, idx_ref, b_ref, c_ref):
+    e_tile = pl.program_id(0)
     kc = pl.program_id(2)
+    tile_e, tile_k = idx_ref.shape
+    tile_o = c_ref.shape[1]
 
     @pl.when(kc == 0)
     def _zero():
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    @pl.when(chunk_any_ref[e, kc] > 0)
+    @pl.when(chunk_any_ref[e_tile, kc] > 0)
     def _acc():
-        a = a_ref[...]                                 # (tile_o, n_ao)
-        ix = idx_ref[...]                              # (tile_e, tile_k)
-        te, tk = ix.shape
-        b = b_ref[...].reshape(te, tk, 5)
-        ag = jnp.take(a, ix.reshape(-1), axis=1)
-        ag = ag.reshape(a.shape[0], te, tk)            # (tile_o, te, tk)
-        # batch over electrons, contract the candidate axis, 5 rhs at once
-        c = jax.lax.dot_general(
-            ag, b, dimension_numbers=(((2,), (1,)), ((1,), (0,))),
-            preferred_element_type=jnp.float32)        # (te, tile_o, 5)
-        c_ref[...] += jnp.transpose(c, (1, 0, 2)).reshape(a.shape[0],
-                                                          te * 5)
+        for e in range(tile_e):
+            def _cand(k, acc, e=e):
+                row = at_ref[pl.ds(idx_ref[e, k], 1), :]      # (1, tile_o)
+                return tuple(a + b_ref[e, k * 5 + f] * row
+                             for f, a in enumerate(acc))
+
+            acc = jax.lax.fori_loop(
+                0, tile_k, _cand,
+                tuple(jnp.zeros((1, tile_o), jnp.float32) for _ in range(5)))
+            for f in range(5):
+                c_ref[pl.ds(e * 5 + f, 1), :] += acc[f]
 
 
 @functools.partial(
     jax.jit, static_argnames=('tile_o', 'tile_k', 'tile_e', 'interpret'))
-def screened_mo_matmul(A: jnp.ndarray, B2d: jnp.ndarray,
+def screened_mo_matmul(At: jnp.ndarray, B2d: jnp.ndarray,
                        idx: jnp.ndarray, chunk_any: jnp.ndarray,
                        *, tile_o: int = 128, tile_k: int = 128,
-                       tile_e: int = 8, interpret: bool = True):
-    """Packed-CSR screened product C2d = scatter(A[:, idx] @ Bp).
+                       tile_e: int = 8, interpret: bool):
+    """Packed-CSR screened product C^T = scatter(A[:, idx] @ Bp)^T.
 
     Args:
-      A: (n_orb, n_ao) f32, n_orb padded to tile_o (n_ao axis resident).
+      At: (n_ao, n_orb) f32 transposed MO coefficients, n_orb padded to
+        tile_o (the n_ao axis is resident).
       B2d: (n_e, K * 5) f32 packed values, electron-major, padded to
         (tile_e, tile_k * 5) multiples; zeros at inactive/padding slots.
       idx: (n_e, K) int32 candidate ids, padded likewise (in-range).
       chunk_any: (e_tiles, k_chunks) int32 — nonzero where the chunk has
         any active candidate (scalar-prefetched skip list).
-      interpret: Python backend (CPU validation) vs real TPU lowering.
+      interpret: run the Python interpreter backend (off the TPU; see
+        ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
-    Returns C2d: (n_orb, n_e * 5) f32.
+    Returns C^T: (n_e * 5, n_orb) f32, row ``e * 5 + f`` for electron e,
+    component f.
     """
-    n_orb, n_ao = A.shape
+    n_ao, n_orb = At.shape
     n_e, k5 = B2d.shape
     assert n_orb % tile_o == 0 and n_e % tile_e == 0
     assert k5 == idx.shape[1] * 5 and idx.shape[1] % tile_k == 0
@@ -84,25 +91,28 @@ def screened_mo_matmul(A: jnp.ndarray, B2d: jnp.ndarray,
     k_chunks = idx.shape[1] // tile_k
     assert chunk_any.shape == (e_tiles, k_chunks)
 
+    smem = pltpu.SMEM
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(e_tiles, o_tiles, k_chunks),
         in_specs=[
-            pl.BlockSpec((tile_o, n_ao), lambda e, o, k, ca: (o, 0)),
-            pl.BlockSpec((tile_e, tile_k), lambda e, o, k, ca: (e, k)),
-            pl.BlockSpec((tile_e, tile_k * 5), lambda e, o, k, ca: (e, k)),
+            pl.BlockSpec((n_ao, tile_o), lambda e, o, k, ca: (0, o)),
+            pl.BlockSpec((tile_e, tile_k), lambda e, o, k, ca: (e, k),
+                         memory_space=smem),
+            pl.BlockSpec((tile_e, tile_k * 5), lambda e, o, k, ca: (e, k),
+                         memory_space=smem),
         ],
-        out_specs=pl.BlockSpec((tile_o, tile_e * 5),
-                               lambda e, o, k, ca: (o, e)),
+        out_specs=pl.BlockSpec((tile_e * 5, tile_o),
+                               lambda e, o, k, ca: (e, o)),
     )
     kwargs = {}
     if not interpret:
-        kwargs['compiler_params'] = pltpu.TPUCompilerParams(
+        kwargs['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'))
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_orb, n_e * 5), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_e * 5, n_orb), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(chunk_any, A, idx, B2d)
+    )(chunk_any, At, idx, B2d)

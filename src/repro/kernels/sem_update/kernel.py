@@ -10,20 +10,21 @@ lowers the naive jnp version to several passes over the ensemble (outer
 product, subtract, dynamic row scatter, accept select); the kernel fuses
 all of it into one read + one write of each walker tile.
 
-Tile layout: the grid runs over walker tiles, each grid step owning a
-``(tile_w, n, n)`` block of ``Minv`` (both trailing axes padded to the f32
-(8, 128) VMEM tile by ``ops.sem_rank1_update`` — the last two dims of a
-3-D block are the constrained ones, the leading walker dim is free).  ``u``
-and ``row`` ride along as ``(tile_w, n)`` panels and broadcast against the
-block in registers; the row replacement is a lane-wise select on a
-broadcasted electron-index iota (no dynamic-slice store), and the
-per-walker accept bit predicates the whole update as a select against the
-resident input tile.  The electron index ``j`` is scalar-prefetched: it is
-the same for every walker, and prefetching keeps it out of the tiled
-operand path.
+Tile layout: the grid runs over (walker tile, row tile); each grid step
+owns a ``(tile_w, tile_r, n)`` block of ``Minv`` (the matrix axes padded to
+the f32 (8, 128) VMEM tile by ``ops.sem_rank1_update``).  Tiling the rows
+keeps the block a few MiB at any width — the update of a row needs only
+that row, ``u``'s entry for it and the shared replacement row.  ``u``
+rides along as a ``(tile_w, tile_r, 1)`` column and ``row`` as a
+``(tile_w, 1, n)`` row, both broadcast against the block in registers; the
+row replacement is a select on a broadcasted electron-index iota (no
+dynamic-slice store), and the per-walker accept bit predicates the whole
+update as a select against the resident input tile.  The electron index
+``j`` is scalar-prefetched: it is the same for every walker, and
+prefetching keeps it out of the tiled operand path.
 
-Walker tiles are independent, so the single grid dimension is declared
-``parallel`` on real TPU.
+Every block is written once, so both grid dimensions are declared
+``parallel`` on the TPU.
 """
 from __future__ import annotations
 
@@ -37,50 +38,53 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(j_ref, minv_ref, u_ref, row_ref, acc_ref, out_ref):
     j = j_ref[0]
-    minv = minv_ref[...]                               # (tile_w, n, n)
-    row = row_ref[...]                                 # (tile_w, n)
-    upd = minv - u_ref[...][:, :, None] * row[:, None, :]
-    elec = jax.lax.broadcasted_iota(jnp.int32, upd.shape, 1)
-    upd = jnp.where(elec == j, row[:, None, :], upd)
-    keep = acc_ref[...][:, 0] == 0                     # (tile_w,)
-    out_ref[...] = jnp.where(keep[:, None, None], minv, upd)
+    minv = minv_ref[...]                               # (tile_w, tile_r, n)
+    row = row_ref[...]                                 # (tile_w, 1, n)
+    upd = minv - u_ref[...] * row
+    first = pl.program_id(1) * minv.shape[1]
+    elec = jax.lax.broadcasted_iota(jnp.int32, upd.shape, 1) + first
+    upd = jnp.where(elec == j, row, upd)
+    keep = acc_ref[...] == 0                           # (tile_w, 1, 1)
+    out_ref[...] = jnp.where(keep, minv, upd)
 
 
-@functools.partial(jax.jit, static_argnames=('tile_w', 'interpret'))
+@functools.partial(jax.jit, static_argnames=('tile_w', 'tile_r',
+                                             'interpret'))
 def sem_update_matmul(minv: jnp.ndarray, u: jnp.ndarray, row: jnp.ndarray,
                       accept: jnp.ndarray, j: jnp.ndarray, *,
-                      tile_w: int = 8, interpret: bool = True):
+                      tile_w: int, tile_r: int, interpret: bool):
     """Raw kernel dispatch on pre-padded operands.
 
     Args:
-      minv: (W, n, n) f32, W a multiple of ``tile_w``, n padded to the
-        f32 VMEM tile (last dim 128-multiple; see ops.sem_rank1_update).
-      u, row: (W, n) f32.
-      accept: (W, 1) int32 (0 = reject); padding walkers pass 0.
+      minv: (W, n, n) f32, W a multiple of ``tile_w`` and n of ``tile_r``
+        (last dim 128-padded on the TPU; see ops.sem_rank1_update).
+      u: (W, n, 1) f32 column; row: (W, 1, n) f32.
+      accept: (W, 1, 1) int32 (0 = reject); padding walkers pass 0.
       j: (1,) int32 electron row index (scalar-prefetched).
-      interpret: Python interpreter backend (CPU validation); False targets
-        real TPU hardware.
+      interpret: run the Python interpreter backend (off the TPU; see
+        ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
     Returns the updated (W, n, n) f32 inverses.
     """
     W, n, _ = minv.shape
-    assert W % tile_w == 0
+    assert W % tile_w == 0 and n % tile_r == 0
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(W // tile_w,),
+        grid=(W // tile_w, n // tile_r),
         in_specs=[
-            pl.BlockSpec((tile_w, n, n), lambda w, jr: (w, 0, 0)),
-            pl.BlockSpec((tile_w, n), lambda w, jr: (w, 0)),
-            pl.BlockSpec((tile_w, n), lambda w, jr: (w, 0)),
-            pl.BlockSpec((tile_w, 1), lambda w, jr: (w, 0)),
+            pl.BlockSpec((tile_w, tile_r, n), lambda w, r, jr: (w, r, 0)),
+            pl.BlockSpec((tile_w, tile_r, 1), lambda w, r, jr: (w, r, 0)),
+            pl.BlockSpec((tile_w, 1, n), lambda w, r, jr: (w, 0, 0)),
+            pl.BlockSpec((tile_w, 1, 1), lambda w, r, jr: (w, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_w, n, n), lambda w, jr: (w, 0, 0)),
+        out_specs=pl.BlockSpec((tile_w, tile_r, n),
+                               lambda w, r, jr: (w, r, 0)),
     )
     kwargs = {}
     if not interpret:
-        # walker tiles write disjoint output blocks: fully parallel
-        kwargs['compiler_params'] = pltpu.TPUCompilerParams(
-            dimension_semantics=('parallel',))
+        # every (walker, row) tile is written once: fully parallel
+        kwargs['compiler_params'] = pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel'))
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..platform import kernel_plan
 from .kernel import sem_update_matmul
 from .ref import sem_update_ref
 
@@ -19,19 +20,20 @@ def _pad_axis(x: jnp.ndarray, axis: int, mult: int):
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=('tile_w', 'interpret'))
 def sem_rank1_update(minv: jnp.ndarray, u: jnp.ndarray, row: jnp.ndarray,
-                     accept: jnp.ndarray, j, *, tile_w: int = 8,
-                     interpret: bool = True) -> jnp.ndarray:
+                     accept: jnp.ndarray, j, *,
+                     tile_w: int = 8) -> jnp.ndarray:
     """Batched Sherman–Morrison rank-1 update + row replacement.
 
     Kernel-dispatching equivalent of ``ref.sem_update_ref`` (same
     signature, same semantics — tests pin the two together): pads the
     walker axis to ``tile_w`` and both matrix axes to the f32 VMEM lane
-    tile (128 on real TPU; 8 under interpret mode, which has no tiling
+    tile (128 on the TPU; 8 in interpret mode, which has no tiling
     constraint), runs ``kernel.sem_update_matmul``, slices back.  Padding
     walkers carry ``accept=0`` so they pass through as zeros; ``j`` may be
     a traced scalar (it is scalar-prefetched, not baked into the grid).
+    ``kernels.platform.kernel_plan`` decides interpret mode and, on the
+    TPU, the walker and row tiles (``tile_w`` applies when interpreting).
 
     Args:
       minv: (W, n, n) running inverses.
@@ -42,21 +44,30 @@ def sem_rank1_update(minv: jnp.ndarray, u: jnp.ndarray, row: jnp.ndarray,
 
     Returns the updated (W, n, n) inverses.
     """
+    plan = kernel_plan('sem_update', (tile_w, 0), n=minv.shape[-1])
+    return _update(minv, u, row, accept, j, tiles=plan.tiles,
+                   interpret=plan.interpret)
+
+
+@functools.partial(jax.jit, static_argnames=('tiles', 'interpret'))
+def _update(minv, u, row, accept, j, *, tiles, interpret):
     W, n, _ = minv.shape
-    # real TPU needs the trailing two block dims on the (8, 128) f32 tile;
+    tile_w, tile_r = tiles
+    # the TPU needs the trailing two block dims on the (8, 128) f32 tile;
     # interpret mode has no tiling constraint, so pad only to 8 there and
     # skip the ~(128/n)^2 traffic blow-up for small spin blocks
-    lane = 128 if not interpret else 8
+    lane = 8 if interpret else 128
     minv_p = _pad_axis(_pad_axis(minv, 1, lane), 2, lane)
-    u_p = _pad_axis(u, 1, lane)
-    row_p = _pad_axis(row, 1, lane)
+    n_p = minv_p.shape[-1]
+    u_p = _pad_axis(u, 1, lane)[:, :, None]
+    row_p = _pad_axis(row, 1, lane)[:, None, :]
     minv_p = _pad_axis(minv_p, 0, tile_w)
     u_p = _pad_axis(u_p, 0, tile_w)
     row_p = _pad_axis(row_p, 0, tile_w)
-    acc = _pad_axis(accept.astype(jnp.int32)[:, None], 0, tile_w)
+    acc = _pad_axis(accept.astype(jnp.int32)[:, None, None], 0, tile_w)
     j_arr = jnp.asarray(j, jnp.int32).reshape((1,))
-    out = sem_update_matmul(minv_p, u_p, row_p, acc, j_arr,
-                            tile_w=tile_w, interpret=interpret)
+    out = sem_update_matmul(minv_p, u_p, row_p, acc, j_arr, tile_w=tile_w,
+                            tile_r=tile_r or n_p, interpret=interpret)
     return out[:W, :n, :n]
 
 

@@ -44,6 +44,7 @@ def _kernel(block_ids_ref, num_active_ref, a_ref, b_ref, c_ref):
     @pl.when(k < num_active_ref[e])
     def _acc():
         c_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
 
@@ -53,7 +54,7 @@ def _kernel(block_ids_ref, num_active_ref, a_ref, b_ref, c_ref):
 def sparse_mo_matmul(A: jnp.ndarray, B2d: jnp.ndarray,
                      block_ids: jnp.ndarray, num_active: jnp.ndarray,
                      *, tile_o: int = 128, tile_k: int = 128,
-                     tile_e5: int = 320, interpret: bool = True):
+                     tile_e5: int = 320, interpret: bool):
     """Block-sparse product C = A @ B2d using per-column-tile block lists.
 
     Args:
@@ -62,8 +63,8 @@ def sparse_mo_matmul(A: jnp.ndarray, B2d: jnp.ndarray,
       block_ids: (n_e_tiles, max_kb) int32 — active k-tile indices per
         column tile (padding entries arbitrary but in-range).
       num_active: (n_e_tiles,) int32 — valid prefix length of block_ids.
-      interpret: run the Python interpreter backend (CPU validation);
-        False targets real TPU hardware.
+      interpret: run the Python interpreter backend (off the TPU; see
+        ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
     Returns C: (n_orb, n_cols) f32.
     """
@@ -92,7 +93,7 @@ def sparse_mo_matmul(A: jnp.ndarray, B2d: jnp.ndarray,
     if not interpret:
         # e/o tiles are independent outputs; only the k accumulation is
         # order-dependent.  (Interpret mode ignores compiler params.)
-        kwargs['compiler_params'] = pltpu.TPUCompilerParams(
+        kwargs['compiler_params'] = pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'))
     return pl.pallas_call(
         _kernel,

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..platform import kernel_plan
 from .kernel import sparse_mo_matmul
 from .ref import mo_products_ref
 
@@ -49,7 +50,7 @@ def ensemble_tile_e(n_e_total: int, tile_e: int, cap: int = 128) -> int:
     is the flattened ``W * n_e`` batch there are plenty of columns, so grow
     the per-walker ``tile_e`` by powers of two up to ``cap`` (128 keeps
     tile_e*5 = 640 lanes = 5 full TPU registers).  Fewer, fuller tiles also
-    shrink the grid, which is what makes the interpret-mode CPU path faster.
+    shrink the grid, which is what makes the interpret-mode path faster.
     """
     te = max(1, tile_e)
     while te < cap and te * 2 <= max(n_e_total, 1):
@@ -65,8 +66,7 @@ def _pow2_cover(n: int, cap: int) -> int:
     return min(t, cap)
 
 
-TILE_E_CAP_TPU = 128      # 5*128 lanes per electron tile; VMEM-bounded
-TILE_E_CAP_INTERPRET = 2048   # CPU interpret mode: grid-step overhead rules
+TILE_E_CAP_INTERPRET = 2048   # interpret mode: grid-step overhead rules
 
 
 def ensemble_tiles(tiles, n_orb: int, n_e_total: int,
@@ -79,33 +79,25 @@ def ensemble_tiles(tiles, n_orb: int, n_e_total: int,
     (interpret mode) or pipeline latency (TPU), so wider tiles that the
     ensemble can actually fill win.  tile_o grows (never shrinks) toward
     covering n_orb — o-padding is bounded by one tile either way; tile_e
-    grows toward ``cap_e``.  ``cap_e=0`` (default) picks the cap for the
-    active backend: on TPU, 128 — 5*128 lanes is the layout optimum and a
-    wider C tile blows the VMEM budget; everywhere else (the interpret-mode
-    CPU path) per-grid-step overhead dominates, so very wide electron tiles
-    win (measured: te 8 -> 2048 is ~25x on the micro-peptide ensemble).
-    tile_k stays at the caller's choice: k-padding costs real zero-flops,
-    coarser k-tiles skip less, and neither tradeoff changes with ensemble
-    size.
+    grows toward ``cap_e``.  ``cap_e=0`` (default) is the interpret-mode
+    cap: there per-grid-step overhead dominates, so very wide electron
+    tiles win (measured: te 8 -> 2048 is ~25x on the micro-peptide
+    ensemble).  On a TPU ``kernels.platform.kernel_plan`` replaces these
+    tiles with its aligned ones.  tile_k stays at the caller's choice:
+    k-padding costs real zero-flops, coarser k-tiles skip less, and neither
+    tradeoff changes with ensemble size.
     """
     if cap_e <= 0:
-        cap_e = (TILE_E_CAP_TPU if jax.default_backend() == 'tpu'
-                 else TILE_E_CAP_INTERPRET)
+        cap_e = TILE_E_CAP_INTERPRET
     to, tk, te = tiles
     return (max(to, _pow2_cover(n_orb, cap_o)), tk,
             ensemble_tile_e(n_e_total, te, cap_e))
 
 
-@functools.partial(jax.jit, static_argnames=(
-    'tile_o', 'tile_k', 'tile_e', 'max_kb', 'interpret'))
 def sparse_mo_products(A: jnp.ndarray, B: jnp.ndarray,
                        ao_active: jnp.ndarray, *,
                        tile_o: int = 128, tile_k: int = 128,
-                       tile_e: int = 128, max_kb: int = 0,
-                       interpret: bool = True) -> jnp.ndarray:
-    # tile_e default 128 (640 lanes = 5x128): measured optimum on the 1AMB
-    # benchmark — smaller tiles skip more but waste MXU lanes
-    # (EXPERIMENTS.md §Perf-QMC iteration 3).
+                       tile_e: int = 128, max_kb: int = 0) -> jnp.ndarray:
     """Tile-sparse C_i = A @ B_i for i=1..5.
 
     A: (n_orb, n_ao); B: (n_ao, n_e, 5); ao_active: (n_e, n_ao) bool.
@@ -118,7 +110,19 @@ def sparse_mo_products(A: jnp.ndarray, B: jnp.ndarray,
     tiles that a per-walker call would pad get filled by neighbouring
     walkers, and each A panel load amortizes over the full population.  Use
     ``ensemble_tile_e`` to pick ``tile_e`` for flattened batches.
+
+    The tiles are the interpreter's; ``kernels.platform.kernel_plan``
+    decides interpret mode and, on a TPU, replaces them with (128, 128, 128).
     """
+    plan = kernel_plan('sparse_mo', (tile_o, tile_k, tile_e))
+    return _products(A, B, ao_active, tiles=plan.tiles, max_kb=max_kb,
+                     interpret=plan.interpret)
+
+
+@functools.partial(jax.jit, static_argnames=('tiles', 'max_kb',
+                                             'interpret'))
+def _products(A, B, ao_active, *, tiles, max_kb, interpret):
+    tile_o, tile_k, tile_e = tiles
     n_orb, n_ao = A.shape
     n_e = B.shape[1]
     Ap = _pad_to(_pad_to(A, 0, tile_o), 1, tile_k)

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.spec import GridConfig, RunSpec, SimGridConfig, build_run
 
 
@@ -139,8 +140,13 @@ def parse_spec(argv=None) -> RunSpec:
 
 
 def main(argv=None):
-    """Parse flags, build the run, execute to completion, print stats."""
+    """Parse flags, build the run, execute to completion, print stats.
+
+    Exits with status 1 when any worker died with an error, after printing
+    each traceback and the statistics the surviving workers gathered.
+    """
     spec = parse_spec(argv)
+    enable_compile_cache()
     run = build_run(spec)
     print(f'run_key={run.run_key} system={spec.system} '
           f'method={spec.method} backend={spec.backend}: '
@@ -151,9 +157,12 @@ def main(argv=None):
         print(f'grid listening on {host}:{port} — attach workers with: '
               f'python -m repro.launch.qmc_worker --connect {host}:{port}')
     avg = run.run()
-    for err in run.worker_errors():
+    errors = run.worker_errors()
+    for err in errors:
         print('WORKER ERROR:\n', err)
     print(avg)
+    if errors:
+        raise SystemExit(f'qmc_run: {len(errors)} worker(s) failed')
     return avg
 
 

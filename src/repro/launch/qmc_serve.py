@@ -52,6 +52,9 @@ def main(argv=None):
     from repro.launch.qmc_worker import parse_address
     host, port = parse_address(args.listen)
     builder = gaussian_builder if args.builder == 'gaussian' else None
+    if builder is None:
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
     service = QMCService(db=args.db, total_workers=args.pool,
                          builder=builder, poll_interval=args.poll_interval,
                          max_active=args.max_active,

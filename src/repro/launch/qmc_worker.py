@@ -41,9 +41,12 @@ def sampler_from_payload(welcome: dict):
         raise SystemExit(
             'manager shipped no run payload (engine-level manager without '
             'a RunSpec?) — pass --sampler gauss:... for transport drills')
+    from repro.compile_cache import enable_compile_cache
     from repro.core.driver import make_propagator
     from repro.runtime.samplers import BlockSampler
     from repro.systems import build_system
+
+    enable_compile_cache()
 
     eps = float(spec.get('screen_eps', -1.0))
     cfg, params = build_system(spec['system'],

@@ -130,6 +130,32 @@ class RunSpec:
             raise ValueError(f'unknown precision {self.precision!r} '
                              f'(choose from {PRECISIONS})')
 
+    def check_platform(self, platform: str | None = None) -> None:
+        """Refuse layouts that start local worker processes on a TPU.
+
+        The parent builds the wavefunction with ``jnp`` before any worker
+        starts, so on a TPU it already holds the chip, and a chip belongs
+        to one process: a ``process`` worker or a localhost ``grid`` worker
+        would fail or hang trying to reach it.  The thread and sim backends
+        and remote grid workers (on their own hosts) are unaffected.
+        ``platform`` defaults to ``jax.default_backend()``.
+        """
+        local = (self.backend == 'process'
+                 or (self.backend == 'grid' and self.net.local_workers))
+        if not local:
+            return
+        if platform is None:
+            import jax
+            platform = jax.default_backend()
+        if platform == 'tpu':
+            what = ('process workers' if self.backend == 'process'
+                    else 'localhost grid workers')
+            raise ValueError(
+                f'backend={self.backend!r} starts {what}, but on a TPU the '
+                f'parent process holds the chip (it builds the wavefunction '
+                f'first) and a chip belongs to one process; use the thread '
+                f'backend, or grid workers attached from other hosts')
+
     def replace(self, **kw) -> 'RunSpec':
         """Functional update (dataclasses.replace convenience)."""
         return dataclasses.replace(self, **kw)
@@ -266,6 +292,7 @@ def build_run(spec: RunSpec, db: ResultDatabase | None = None) -> QMCRun:
     """
     from repro.core.driver import make_propagator
 
+    spec.check_platform()
     screen_eps = spec.screen_eps if spec.screen_eps >= 0 else None
     cfg, params = build_system(spec.system, n_det=spec.n_det,
                                ci_seed=spec.seed, screen_eps=screen_eps)

@@ -260,12 +260,11 @@ def _sweep_jaxpr(cfg, params, path):
     The state/keys are built OUTSIDE the x64 context (f32, like a real
     run); the trace then exposes any un-pinned internal constant — basis
     tables (``aos._basis_consts``), uniform draws — that would promote."""
-    from jax.experimental import enable_x64
     W = 2
     r = sample_positions(params, jax.random.PRNGKey(0), W, cfg.n_elec)
     ens = evaluate_sem(cfg, params, r)
     wkeys = Population().walker_keys(jax.random.PRNGKey(1), W)
-    with enable_x64():
+    with jax.enable_x64(True):
         if path == 'fused':
             jx = jax.make_jaxpr(
                 lambda e, k: sem._fused_sweeps(

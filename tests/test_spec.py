@@ -98,3 +98,111 @@ def test_build_system_catalog():
     with pytest.raises(KeyError):
         from repro.systems.bench import paper_system
         paper_system('not-a-system')
+
+
+@pytest.mark.parametrize('kw', [
+    dict(backend='process'),
+    dict(backend='grid'),                      # spawns localhost workers
+], ids=['process', 'grid-local'])
+def test_tpu_refuses_local_worker_processes(kw):
+    """On a TPU the parent holds the chip, so layouts whose workers are
+    local processes are refused, saying why."""
+    spec = RunSpec(**kw)
+    with pytest.raises(ValueError, match='holds the chip'):
+        spec.check_platform('tpu')
+    spec.check_platform('cpu')                 # off the TPU: unchanged
+
+
+@pytest.mark.parametrize('kw', [
+    dict(backend='thread'),
+    dict(backend='sim'),
+], ids=['thread', 'sim'])
+def test_tpu_keeps_in_process_and_remote_workers(kw):
+    from repro.runtime import GridConfig
+    RunSpec(**kw).check_platform('tpu')
+    RunSpec(backend='grid',
+            net=GridConfig(local_workers=False)).check_platform('tpu')
+
+
+class _FailingSampler:
+    """Worker-protocol sampler whose every sub-block raises."""
+
+    def init_state(self, worker_id, seed, walkers=None):
+        return None
+
+    def run_subblock(self, state, step):
+        raise RuntimeError('injected sampler fault')
+
+
+def test_qmc_run_exits_nonzero_on_worker_error(monkeypatch, capsys):
+    """A worker that dies must fail the CLI run, not print and exit 0."""
+    from types import SimpleNamespace
+
+    from repro.launch import qmc_run
+    from repro.runtime import QMCManager, RunControl
+
+    def fake_build_run(spec):
+        mgr = QMCManager(_FailingSampler(), 'deadbeef',
+                         RunControl(max_blocks=2, poll_interval=0.01),
+                         backend=ThreadBackend(1))
+        return SimpleNamespace(run_key='deadbeef', run=mgr.run,
+                               worker_errors=mgr.worker_errors,
+                               backend=mgr.backend)
+
+    monkeypatch.setattr(qmc_run, 'build_run', fake_build_run)
+    with pytest.raises(SystemExit) as exc:
+        qmc_run.main(['--backend', 'thread', '--workers', '1'])
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert 'WORKER ERROR' in out and 'injected sampler fault' in out
+
+
+_CACHE_PROBE = '''
+import sys
+import jax, jax.numpy as jnp
+from pathlib import Path
+from repro.compile_cache import enable_compile_cache
+jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **kw: hits.append(name)
+    if name == '/jax/compilation_cache/cache_hits' else None)
+print(enable_compile_cache(Path(sys.argv[1])))
+jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((8, 8))).block_until_ready()
+print(len(hits))
+'''
+
+
+def test_compile_cache_fixed_dir_and_env_override(tmp_path):
+    """Without JAX_COMPILATION_CACHE_DIR the cache goes to the fixed
+    directory and a second process reads it back; with it, JAX's own
+    directory is used and the fixed one is never created."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / 'src')
+    env = {k: v for k, v in os.environ.items()
+           if k != 'JAX_COMPILATION_CACHE_DIR'}
+    env['PYTHONPATH'] = src
+    fixed = tmp_path / 'fixed'
+
+    def probe(env):
+        out = subprocess.run([sys.executable, '-c', _CACHE_PROBE,
+                              str(fixed)], env=env, capture_output=True,
+                             text=True, timeout=120, check=True)
+        path, hits = out.stdout.split()
+        return path, int(hits)
+
+    assert probe(env) == (str(fixed), 0)
+    assert any(fixed.iterdir())
+    path, hits = probe(env)
+    assert path == str(fixed) and hits >= 1     # read back
+
+    own = tmp_path / 'own'
+    fixed_files = sorted(fixed.iterdir())
+    path, hits = probe({**env, 'JAX_COMPILATION_CACHE_DIR': str(own)})
+    assert (path, hits) == (str(own), 0)
+    assert any(own.iterdir())
+    assert sorted(fixed.iterdir()) == fixed_files
