@@ -7,9 +7,11 @@ One process, phases in order, no fallback.  On one chip it runs
 ``build_run(RunSpec(...)).run()`` — what ``python -m repro.launch.qmc_run``
 does — on the thread backend for the paper's ``smallest`` system (158
 electrons, 346 AOs, nothing reduced) with the methods ``vmc``, ``dmc`` and
-``sem-vmc``; checks that the single-electron-move state still tracks a
-fresh ``slogdet``/``inv`` of the same walkers to the DESIGN.md §6 contract;
-then compiles each Pallas kernel for the chip at ``smallest`` widths and
+``sem-vmc``; checks each method's local energies against the host CPU
+on the same walkers, one DMC generation against the CPU from the same
+walkers and keys, and that the single-electron-move state still tracks a fresh
+``slogdet``/``inv`` of the same walkers to the DESIGN.md §6 contract; then
+compiles each Pallas kernel for the chip at ``smallest`` widths and
 compares it with its ``ref.py``.  With ``--four-chips`` it runs only the
 walker mesh: ``vmc`` and ``dmc`` with ``shards=4`` against ``shards=1``
 under the same keys.
@@ -35,6 +37,18 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / 'src'))
 SYSTEM = 'smallest'
 WALKERS = 64
 DRIFT_TOL = 1e-4          # DESIGN.md §6, tests/test_sem.py
+# the chip's per-walker local energies against the host CPU's on the same
+# walkers (both fp32), median over walkers, relative to the energy scale.
+# Two fp32 evaluation orders on one CPU differ by 1.3e-6 at the median and
+# up to 9e-3 on the worst-conditioned of 16 walkers at `smallest`, so the
+# median is what is held; a bf16-pass contraction moves every walker.
+ELOC_TOL = 1e-4
+# one DMC generation from the same walkers and keys, chip against the host
+# CPU: energy within this fraction of the CPU's, acceptance within this
+# many walkers.  (Over a whole sub-block the two walks part: a rounding
+# flips one accept, and branching then follows different walkers.)
+DMC_STEP_RTOL = 1e-2
+DMC_STEP_FLIPS = 2
 # a phase that runs longer than this is a failure: the script dumps every
 # thread's stack and exits 1 instead of hanging
 PHASE_LIMIT_S = 420
@@ -90,6 +104,61 @@ def _assert_tracks_fresh(ens, fresh) -> float:
     return max(worst, d_logdet)
 
 
+def _cpu():
+    import jax
+    return jax.default_device(jax.devices('cpu')[0])
+
+
+def _eloc_witness(run, state) -> float:
+    """The chip's local energies of the initial walkers against the host
+    CPU's evaluation of the same positions (the propagator's own restart
+    path); returns the median and the largest difference, relative to the
+    energy scale."""
+    import jax
+    import numpy as np
+    ens = run.sampler.driver._ensemble_part(state[1])
+    r = np.asarray(ens.r)
+    e_chip = np.asarray(ens.e_loc, np.float64)
+    with _cpu():
+        params = jax.device_put(run.sampler.params, jax.devices('cpu')[0])
+        st = run.sampler.propagator.init(params, None, r.shape[0], walkers=r)
+        e_cpu = np.asarray(run.sampler.driver._ensemble_part(st).e_loc,
+                           np.float64)
+    _check(bool(np.all(np.isfinite(e_chip))), 'non-finite local energy')
+    scale = max(float(np.median(np.abs(e_cpu))), 1.0)
+    diff = np.abs(e_chip - e_cpu) / scale
+    med = float(np.median(diff))
+    _check(med <= ELOC_TOL,
+           f'local energies differ from the CPU by {med:.3e} of the '
+           f'energy scale {scale:.1f} at the median walker (> {ELOC_TOL})')
+    return dict(eloc_vs_cpu_median=med, eloc_vs_cpu_max=float(diff.max()))
+
+
+def _dmc_step_witness(spec, walkers) -> dict:
+    """One DMC generation from ``walkers`` with the same keys, on the chip
+    and on the host CPU: block energy and acceptance must agree."""
+    import contextlib
+    from repro.launch.spec import build_run
+    one = spec.replace(steps=1)
+    out = {}
+    for where, ctx in (('chip', contextlib.nullcontext()), ('cpu', _cpu())):
+        with ctx:
+            run = build_run(one)
+            state = run.sampler.init_state(0, spec.seed, walkers=walkers)
+            _, out[where], _, _ = run.sampler.run_subblock(state, 0)
+    chip, cpu = out['chip'], out['cpu']
+    d_e = abs(chip.e_mean - cpu.e_mean)
+    _check(d_e <= DMC_STEP_RTOL * abs(cpu.e_mean),
+           f'one DMC step: energy {chip.e_mean} vs CPU {cpu.e_mean}')
+    d_a = abs(chip.aux['accept'] - cpu.aux['accept'])
+    _check(d_a * spec.n_walkers <= DMC_STEP_FLIPS,
+           f'one DMC step: acceptance {chip.aux["accept"]} vs CPU '
+           f'{cpu.aux["accept"]}')
+    return dict(step_energy=chip.e_mean, step_energy_cpu=cpu.e_mean,
+                step_accept=chip.aux['accept'],
+                step_accept_cpu=cpu.aux['accept'])
+
+
 def qmc_run_phase(method: str, walkers: int = WALKERS) -> None:
     """One method through build_run(...).run() on the thread backend."""
     import jax
@@ -101,10 +170,17 @@ def qmc_run_phase(method: str, walkers: int = WALKERS) -> None:
     # the first sub-block compiles the block program (the worker thread
     # reuses it) and, for sem-vmc, feeds the drift check
     t0 = time.perf_counter()
-    state = run.sampler.init_state(0, spec.seed)
+    state = jax.block_until_ready(run.sampler.init_state(0, spec.seed))
+    compile_s = time.perf_counter() - t0
+    eloc_err = _eloc_witness(run, state)
+    witness = {}
+    if method == 'dmc':
+        r0 = np.asarray(run.sampler.driver._ensemble_part(state[1]).r)
+        witness = _dmc_step_witness(spec, r0)
+    t0 = time.perf_counter()
     state, first, _, _ = run.sampler.run_subblock(state, 0)
     jax.block_until_ready(state)
-    compile_s = time.perf_counter() - t0
+    compile_s += time.perf_counter() - t0
     drift = None
     if method == 'sem-vmc':
         from repro.core.sem import evaluate_sem
@@ -123,14 +199,14 @@ def qmc_run_phase(method: str, walkers: int = WALKERS) -> None:
            f'{method}: energy {avg.energy} +/- {avg.error}')
     accept = [b.aux.get('accept', float('nan'))
               for b in run.db.blocks(run.run_key)]
-    if method in ('vmc', 'sem-vmc'):
-        _check(all(0.0 < a < 1.0 for a in accept),
-               f'{method}: acceptance {accept} not inside (0, 1)')
+    _check(all(0.0 < a < 1.0 for a in accept),
+           f'{method}: acceptance {accept} not inside (0, 1)')
     _emit(phase=f'qmc_run:{SYSTEM}/{method}', device=_device(jax),
           walkers=walkers, steps=spec.steps, blocks=avg.n_blocks,
           compile_s=compile_s, run_s=run_s, energy=avg.energy,
           error=avg.error, accept=float(np.mean(accept)),
-          first_subblock_energy=first.e_mean, drift=drift)
+          first_subblock_energy=first.e_mean, drift=drift, **eloc_err,
+          **witness)
 
 
 def _close(name, got, want, rtol, atol) -> float:
@@ -247,34 +323,28 @@ def kernels_phase(walkers: int = WALKERS) -> None:
     logu = jnp.log(jax.random.uniform(ks[2], (walkers, n), minval=1e-6))
 
     def sweep(use_kernel):
-        # the propagator sums a sweep's log-ratios from zero
-        # (sem._fold_log_ratios adds them to the running log-determinant)
         return fused_sweep_block(
-            ens.minv_up, phi, ens.r, r_prop, en, logu, ens.sign,
-            jnp.zeros_like(ens.logdet), params.jastrow.b_ee, offset=0,
-            n_up=n, use_kernel=use_kernel, tile_w=8)
+            ens.minv_up, phi, ens.r, r_prop, en, logu, params.jastrow.b_ee,
+            offset=0, n_up=n, use_kernel=use_kernel, tile_w=8)
 
     got, first_s, run_s = _timed(lambda: sweep(True))
     with highest:
         want = jax.block_until_ready(sweep(False))
-    names = ('r', 'minv', 'sign', 'logdet', 'P', 'rdet', 'accept')
+    names = ('r', 'minv', 'P', 'rdet', 'accept')
     for name, a, b in zip(names, got, want):
-        if name in ('r', 'sign', 'accept'):
+        if name in ('r', 'accept'):
             _check(bool(np.array_equal(np.asarray(a), np.asarray(b))),
                    f'fused_sweep: {name} differs from the scan oracle')
-    _check(bool(np.any(np.asarray(got[6]))), 'fused_sweep accepted nothing')
+    _check(bool(np.any(np.asarray(got[4]))), 'fused_sweep accepted nothing')
     a, b = np.asarray(got[1], np.float64), np.asarray(want[1], np.float64)
     d_minv = float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1.0))
-    d_logdet = float(np.max(np.abs(np.asarray(got[3], np.float64)
-                                   - np.asarray(want[3], np.float64))))
-    _check(d_minv <= DRIFT_TOL and d_logdet <= DRIFT_TOL,
-           f'fused_sweep: minv {d_minv:.3e} / logdet {d_logdet:.3e} vs the '
-           f'scan oracle exceed {DRIFT_TOL}')
+    _check(d_minv <= DRIFT_TOL,
+           f'fused_sweep: minv {d_minv:.3e} vs the scan oracle exceeds '
+           f'{DRIFT_TOL}')
     plan = kernel_plan('fused_sweep', (8,), n=n, n_e=cfg.n_elec)
     _check(not plan.interpret, 'fused_sweep: planned for the interpreter')
     _emit(phase='kernel:fused_sweep', device=_device(jax), tiles=plan.tiles,
-          compile_s=first_s, run_s=run_s, minv_drift=d_minv,
-          logdet_drift=d_logdet)
+          compile_s=first_s, run_s=run_s, minv_drift=d_minv)
 
 
 def mesh_phase(method: str, shards: int = 4, walkers: int = WALKERS,

@@ -31,18 +31,58 @@ def _monomial_1d(x: jnp.ndarray, n: jnp.ndarray):
     powers = [jnp.ones_like(x)]
     for _ in range(MAX_POW):
         powers.append(powers[-1] * x)
-    powers = jnp.stack(powers, axis=-1)  # (..., n_ao, MAX_POW+1)
     nf = n.astype(x.dtype)
 
-    def _take(k):  # x^{clip(n+k, 0)} via clamped table lookup
+    def _take(k):  # x^{clip(n+k, 0)}: a select per power, not a gather
+        # (on a TPU v5e the gather of this table from the walker-batched
+        # MO tensor pass halted the chip)
         kk = jnp.clip(n + k, 0, MAX_POW)
-        kk = jnp.broadcast_to(kk, x.shape)[..., None]
-        return jnp.take_along_axis(powers, kk, axis=-1)[..., 0]
+        out = powers[0]
+        for p in range(1, MAX_POW + 1):
+            out = jnp.where(kk == p, powers[p], out)
+        return out
 
     f = _take(0)
     df = nf * _take(-1)
     d2f = nf * (nf - 1.0) * _take(-2)
     return f, df, d2f
+
+
+def atom_to_ao(x: jnp.ndarray, ao_atom) -> jnp.ndarray:
+    """``x[..., ao_atom]`` for the basis's host ``ao_atom``, without a gather.
+
+    Each run of AOs on one atom is a static slice of ``x`` broadcast to the
+    run's length; the TPU then sees slices and one concatenate instead of
+    a gather of a walker-batched operand by constant indices (the pattern
+    that halted a TPU v5e in the MO tensor pass).  Bitwise equal to the
+    gather.  One slice per atom: for per-pass masks, not per-move work.
+    """
+    ao_atom = np.asarray(ao_atom)
+    starts = np.flatnonzero(np.r_[True, ao_atom[1:] != ao_atom[:-1]])
+    ends = np.r_[starts[1:], ao_atom.size]
+    parts = [jnp.broadcast_to(x[..., int(ao_atom[s]), None],
+                              x.shape[:-1] + (int(e - s),))
+             for s, e in zip(starts, ends)]
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _ao_geometry(basis: BasisSet, coords: jnp.ndarray, r_elec: jnp.ndarray):
+    """Electron-AO displacements without gathering walker-batched arrays.
+
+    Returns ``d`` (..., n_ao, 3) = r - R_atom(ao), its ``r2``, the per-AO
+    in-radius mask and the per-atom mask (..., n_atoms).  The per-AO terms
+    are computed from the (n_atoms, 3) nuclear table expanded to AOs, with
+    the same arithmetic as the per-atom terms, so they are bitwise equal to
+    indexing the per-atom arrays by ``ao_atom``.
+    """
+    ao_atom = np.asarray(basis.ao_atom)
+    radius2 = jnp.asarray(basis.atom_radius2, jnp.float32)
+    dxyz_at = r_elec[..., None, :] - coords                  # (..., n_at, 3)
+    atom_active = jnp.sum(dxyz_at * dxyz_at, axis=-1) < radius2
+    d = r_elec[..., None, :] - coords[ao_atom]               # (..., n_ao, 3)
+    r2 = jnp.sum(d * d, axis=-1)                             # (..., n_ao)
+    active = r2 < jnp.asarray(basis.atom_radius2[ao_atom], jnp.float32)
+    return d, r2, active, atom_active
 
 
 def _basis_consts(basis: BasisSet):
@@ -90,14 +130,8 @@ def eval_ao_block(basis: BasisSet, coords: jnp.ndarray, r_elec: jnp.ndarray):
         # schedules the batched elementwise pipeline measurably better than
         # the same graph with a single fused W*n_e axis (CPU and TPU).
         return jax.vmap(lambda r: eval_ao_block(basis, coords, r))(r_elec)
-    ao_atom, ao_pow, prim_c, prim_a, radius2 = _basis_consts(basis)
-
-    dxyz_at = r_elec[..., None, :] - coords                  # (..., n_at, 3)
-    r2_at = jnp.sum(dxyz_at * dxyz_at, axis=-1)              # (..., n_at)
-    atom_active = r2_at < radius2
-
-    d = dxyz_at[..., ao_atom, :]                             # (..., n_ao, 3)
-    r2 = r2_at[..., ao_atom]                                 # (..., n_ao)
+    _, ao_pow, prim_c, prim_a, _ = _basis_consts(basis)
+    d, r2, active, atom_active = _ao_geometry(basis, coords, r_elec)
 
     # Radial part and its radial derivatives:
     #   g   = sum_k c_k e^{-a_k r^2}
@@ -133,7 +167,6 @@ def eval_ao_block(basis: BasisSet, coords: jnp.ndarray, r_elec: jnp.ndarray):
 
     B = jnp.stack([val] + grads + [lap], axis=-1)            # (..., n_ao, 5)
     # screening: exact zeros outside the atomic radius (paper's sparsity)
-    active = atom_active[..., ao_atom]                       # (..., n_ao)
     B = jnp.where(active[..., None], B, 0.0)
     # (..., n_e, n_ao, 5) -> (..., n_ao, n_e, 5): per-walker 2-D transposes
     return jnp.swapaxes(B, -3, -2), atom_active
@@ -158,14 +191,8 @@ def eval_ao_values(basis: BasisSet, coords: jnp.ndarray,
       vals: (n_ao, N) float32 AO values, exact zeros outside atomic radii.
       atom_active: (N, n_atoms) bool — point within atomic radius.
     """
-    ao_atom, ao_pow, prim_c, prim_a, radius2 = _basis_consts(basis)
-
-    dxyz_at = r_elec[..., None, :] - coords                  # (N, n_at, 3)
-    r2_at = jnp.sum(dxyz_at * dxyz_at, axis=-1)              # (N, n_at)
-    atom_active = r2_at < radius2
-
-    d = dxyz_at[..., ao_atom, :]                             # (N, n_ao, 3)
-    r2 = r2_at[..., ao_atom]                                 # (N, n_ao)
+    _, ao_pow, prim_c, prim_a, _ = _basis_consts(basis)
+    d, r2, active, atom_active = _ao_geometry(basis, coords, r_elec)
     expo = jnp.exp(-prim_a[None] * r2[..., None])            # (N, n_ao, P)
     g = jnp.sum(prim_c[None] * expo, axis=-1)                # radial part
     poly = jnp.ones_like(g)
@@ -174,9 +201,7 @@ def eval_ao_values(basis: BasisSet, coords: jnp.ndarray,
         # returned alongside are dead code XLA prunes under jit
         f, _, _ = _monomial_1d(d[..., l], ao_pow[:, l])
         poly = poly * f
-    val = poly * g
-    active = atom_active[..., ao_atom]                       # (N, n_ao)
-    val = jnp.where(active, val, 0.0)
+    val = jnp.where(active, poly * g, 0.0)
     return val.T, atom_active
 
 
@@ -290,7 +315,7 @@ def active_ao_indices(basis: BasisSet, atom_active: jnp.ndarray, k_max: int,
     if ao_mask is None:
         global _MASK_FALLBACKS
         _MASK_FALLBACKS += 1
-        ao_mask = atom_active[:, jnp.asarray(basis.ao_atom)]  # (n_e, n_ao)
+        ao_mask = atom_to_ao(atom_active, basis.ao_atom)      # (n_e, n_ao)
     mask = ao_mask
     count = jnp.sum(mask.astype(jnp.int32), axis=-1)
     n_e, n_ao = mask.shape

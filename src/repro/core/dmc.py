@@ -6,7 +6,13 @@ Per generation (paper §II):
   2. fixed-node constraint: moves that flip sign(Psi_T) are rejected
      (nodes act as infinite barriers);
   3. branching weight w = exp(-tau_eff/2 [(E_L(R')-E_T) + (E_L(R)-E_T)])
-     (eq. 3);
+     (eq. 3), each E_L - E_T capped at +/- sqrt(n_e / tau), the
+     size-consistent cutoff of Zen et al., PRB 93, 241118 (2016) at
+     alpha = 1 (their 0.2 took 0.11 Ha off the projection of the cusp-less
+     H2 trial function).  Without it, walkers near a nucleus, where a
+     Gaussian basis has no cusp, take the whole population: at the paper's
+     158-electron system the first DMC block read -12492 Ha and the
+     acceptance fell to 0;
   4. reconfiguration (reconfig.py) keeps the population size constant;
      the population-mean weight enters the trailing global weight
      Pi_t = prod_{s in window} w_bar_s, which weights the energy estimator
@@ -90,8 +96,12 @@ class DMCPropagator:
         # pop.mean of 0/1 is reduction-order exact for power-of-two shards
         acc_frac = pop.mean(accept.astype(jnp.float32))
         tau_eff = self.tau * jnp.maximum(acc_frac, 1e-3)
-        w = jnp.exp(-0.5 * tau_eff *
-                    (moved.e_loc + ens.e_loc - 2.0 * state.e_trial))
+        e_cut = (self.cfg.n_elec / self.tau) ** 0.5
+
+        def _dev(e):
+            return jnp.clip(e - state.e_trial, -e_cut, e_cut)
+
+        w = jnp.exp(-0.5 * tau_eff * (_dev(moved.e_loc) + _dev(ens.e_loc)))
         w = jnp.clip(w, 0.0, 4.0)              # guard rare E_L spikes
 
         # global reconfiguration: identical resampling for any mesh shape

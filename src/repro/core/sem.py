@@ -16,14 +16,15 @@ kernel in ``kernels.sem_update.kernel``, selected by
 point are needed (``aos.eval_ao_values``) plus an O(n_e) Jastrow delta
 (``jastrow.jastrow_delta_one_electron``).  After the sweep one full MO
 tensor pass assembles the local energy through the *maintained* inverses
-(``slater.ratios_from_inverse`` — no factorization), with
+(``slater.ratios_from_inverse``), with
 
-* a Newton–Schulz ``refine_inverse`` corrector every sweep, and
-* a full ``slogdet``/``inv`` refresh every ``cfg.sem_refresh`` sweeps,
+* a Newton–Schulz ``refine_inverse`` corrector every sweep,
+* a full ``inv`` refresh every ``cfg.sem_refresh`` sweeps, and
+* sign and log|det| from one batched LU of the sweep's Slater matrices,
 
-bounding fp32 drift of the running inverse and log-determinant (DESIGN.md
-§6 has the error-bound argument; tests pin <=1e-4 agreement with a fresh
-recompute between refreshes).
+bounding fp32 drift of the running inverse (DESIGN.md §6 has the
+error-bound argument; tests pin <=1e-4 agreement with a fresh recompute
+between refreshes).
 
 ``SEMVMCPropagator`` is a standard ``driver.Propagator`` plug-in: the same
 ``EnsembleDriver`` block loop, ``--shards N`` walker-mesh sharding, runtime
@@ -88,8 +89,8 @@ class SEMEnsemble(NamedTuple):
     r: jnp.ndarray          # (W, n_e, 3)
     minv_up: jnp.ndarray    # (W, n_up, n_up) running inverse (elec, orb)
     minv_dn: jnp.ndarray    # (W, n_dn, n_dn)
-    sign: jnp.ndarray       # (W,) running sign of Det_up * Det_dn (ref det)
-    logdet: jnp.ndarray     # (W,) running sum of log|det| over spins (ref)
+    sign: jnp.ndarray       # (W,) sign of Det_up * Det_dn (ref det)
+    logdet: jnp.ndarray     # (W,) sum of log|det| over spins (ref det)
     log_psi: jnp.ndarray    # (W,) logdet [+ log|CI sum|] + J
     e_loc: jnp.ndarray      # (W,)
     p_up: jnp.ndarray       # (W, n_orb, n_up) shared table (ci; else (W,0,0))
@@ -189,8 +190,8 @@ def _energy_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
 
     The factorization-free sibling of ``wavefunction._finish_state``:
     drift/Laplacian ratios come from ``slater.ratios_from_inverse`` against
-    the running ``minv`` blocks, so the only O(n^3)-ish work left per sweep
-    is the MO tensor build the energy needs anyway.  With ``cfg.ci`` the
+    the running ``minv`` blocks; ``sign``/``logdet`` are passed in (the
+    propagator takes them from one batched LU per sweep).  With ``cfg.ci`` the
     shared ratio tables and all determinant ratios are (re)built from the
     same maintained inverses (one GEMM + gathered k×k dets per spin —
     still zero factorizations) and grad/lap become the CI-weighted
@@ -281,7 +282,7 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
                       carry, ci_args=None):
     """One Metropolis trial per electron of one spin block, all walkers.
 
-    ``carry`` is ``(r, minv, sign, logdet)`` with ``minv`` the running
+    ``carry`` is ``(r, minv)`` with ``minv`` the running
     inverse of THIS spin block; electrons ``offset .. offset+n_blk-1`` are
     scanned in order, so a later electron sees the earlier accepted moves
     of the same sweep (sequential-sweep semantics, batched over walkers).
@@ -303,9 +304,9 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
 
     def _move(carry, e):
         if ci is not None:
-            r, minv, sign, logdet, P, rdet = carry
+            r, minv, P, rdet = carry
         else:
-            r, minv, sign, logdet = carry
+            r, minv = carry
         j = offset + e
         keys = jax.vmap(lambda k: jax.random.fold_in(k, j))(wkeys)
 
@@ -377,15 +378,13 @@ def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
         row = minv[:, e, :] / safe[:, None]
         minv = _apply_update(cfg, minv, u_vec, row, accept, e)
         r = r.at[:, j].set(jnp.where(accept[:, None], r_new, r_old))
-        logdet = logdet + jnp.where(accept, log_ratio, 0.0)
-        sign = sign * jnp.where(accept, jnp.sign(ratio), 1.0)
         acc_frac = jnp.mean(accept.astype(jnp.float32))
         if ci is not None:
             P = jnp.where(accept[:, None, None],
                           P - g_vec[:, :, None] * row[:, None, :], P)
             rdet = jnp.where(accept[:, None], rdet_new, rdet)
-            return (r, minv, sign, logdet, P, rdet), acc_frac
-        return (r, minv, sign, logdet), acc_frac
+            return (r, minv, P, rdet), acc_frac
+        return (r, minv), acc_frac
 
     return jax.lax.scan(_move, carry, jnp.arange(n_blk))
 
@@ -495,7 +494,7 @@ def _fused_sweeps(cfg, params, ens, minv_up, minv_dn, p_up, p_dn, wkeys,
     stream is the same; statistics agree with the per-move sweep in
     distribution, not move-for-move.
 
-    Returns (r, minv_up, minv_dn, sign, logdet, accepts) — ``accepts`` the
+    Returns (r, minv_up, minv_dn, accepts) — ``accepts`` the
     (n_e,) per-move mean acceptance fractions.
     """
     from repro.kernels.fused_sweep.ops import fused_sweep_block
@@ -534,24 +533,24 @@ def _fused_sweeps(cfg, params, ens, minv_up, minv_dn, p_up, p_dn, wkeys,
     phi_up, phi_dn = _fused_phi_all(cfg, params, A_up, A_dn, r_prop)
     ci_up = (p_up, ens.rdet_up, ens.rdet_dn, ci.holes_up, ci.parts_up,
              ci.coeffs) if ci is not None else None
-    r, minv_up, sign, logdet, _, rdet_up, acc_up = fused_sweep_block(
+    r, minv_up, _, rdet_up, acc_up = fused_sweep_block(
         minv_up, phi_up, ens.r, r_prop[:, :n_up], en_delta[:, :n_up],
-        logu[:, :n_up], ens.sign, ens.logdet, jas.b_ee, ci_up,
+        logu[:, :n_up], jas.b_ee, ci_up,
         offset=0, n_up=n_up, use_kernel=use_kernel, tile_w=tile_w)
 
     if n_dn > 0:
         ci_dn = (p_dn, ens.rdet_dn, rdet_up, ci.holes_dn, ci.parts_dn,
                  ci.coeffs) if ci is not None else None
-        r, minv_dn, sign, logdet, _, _, acc_dn = fused_sweep_block(
+        r, minv_dn, _, _, acc_dn = fused_sweep_block(
             minv_dn, phi_dn, r, r_prop[:, n_up:], en_delta[:, n_up:],
-            logu[:, n_up:], sign, logdet, jas.b_ee, ci_dn,
+            logu[:, n_up:], jas.b_ee, ci_dn,
             offset=n_up, n_up=n_up, use_kernel=use_kernel, tile_w=tile_w)
         accepts = jnp.concatenate([
             jnp.mean(acc_up.astype(jnp.float32), axis=0),
             jnp.mean(acc_dn.astype(jnp.float32), axis=0)])
     else:
         accepts = jnp.mean(acc_up.astype(jnp.float32), axis=0)
-    return r, minv_up, minv_dn, sign, logdet, accepts
+    return r, minv_up, minv_dn, accepts
 
 
 class SEMVMCPropagator:
@@ -597,37 +596,34 @@ class SEMVMCPropagator:
         p_dn = _to_compute(ens.p_dn, cfg)
 
         if cfg.method in ('fused', 'fused-kernel'):
-            r, minv_up, minv_dn, sign, logdet, accepts = _fused_sweeps(
+            r, minv_up, minv_dn, accepts = _fused_sweeps(
                 cfg, params, ens, minv_up, minv_dn, p_up, p_dn, wkeys,
                 self.step_size)
         else:
             A_up, A_dn = _mo_blocks(cfg, params)
             if ci is not None:
-                carry = (ens.r, minv_up, ens.sign, ens.logdet,
-                         p_up, ens.rdet_up)
-                (r, minv_up, sign, logdet, _, rdet_up), acc_up = \
+                carry = (ens.r, minv_up, p_up, ens.rdet_up)
+                (r, minv_up, _, rdet_up), acc_up = \
                     _sweep_spin_block(
                         cfg, params, A_up, 0, cfg.n_up, wkeys,
                         self.step_size, carry,
                         ci_args=(ci.holes_up, ci.parts_up, ens.rdet_dn))
             else:
-                carry = (ens.r, minv_up, ens.sign, ens.logdet)
-                (r, minv_up, sign, logdet), acc_up = _sweep_spin_block(
+                (r, minv_up), acc_up = _sweep_spin_block(
                     cfg, params, A_up, 0, cfg.n_up, wkeys, self.step_size,
-                    carry)
+                    (ens.r, minv_up))
             if cfg.n_dn > 0:
                 if ci is not None:
-                    carry = (r, minv_dn, sign, logdet, p_dn, ens.rdet_dn)
-                    (r, minv_dn, sign, logdet, _, _), acc_dn = \
+                    carry = (r, minv_dn, p_dn, ens.rdet_dn)
+                    (r, minv_dn, _, _), acc_dn = \
                         _sweep_spin_block(
                             cfg, params, A_dn, cfg.n_up, cfg.n_dn, wkeys,
                             self.step_size, carry,
                             ci_args=(ci.holes_dn, ci.parts_dn, rdet_up))
                 else:
-                    carry = (r, minv_dn, sign, logdet)
-                    (r, minv_dn, sign, logdet), acc_dn = _sweep_spin_block(
+                    (r, minv_dn), acc_dn = _sweep_spin_block(
                         cfg, params, A_dn, cfg.n_up, cfg.n_dn, wkeys,
-                        self.step_size, carry)
+                        self.step_size, (r, minv_dn))
                 accepts = jnp.concatenate([acc_up, acc_dn])
             else:
                 accepts = acc_up
@@ -638,30 +634,31 @@ class SEMVMCPropagator:
         up, dn = _slater_blocks(cfg, Cw)
         sweeps = state.sweeps + 1
 
+        # sign and log|det| come from a batched LU of the sweep's Slater
+        # matrices, not from a running sum of the moves' log-ratios: at
+        # |log det| ~ 600 (the paper's 158 electrons) one fp32 ulp is 6e-5,
+        # and ~100 accepted moves per sweep drift such a sum past the 1e-4
+        # contract (DESIGN.md §6).  Only the inverse is maintained: Newton–
+        # Schulz corrected every sweep, rebuilt every ``sem_refresh``.
+        sign, logdet = jnp.linalg.slogdet(up[..., 0])
+        if cfg.n_dn > 0:
+            sd, ld = jnp.linalg.slogdet(dn[..., 0])
+            sign, logdet = sign * sd, logdet + ld
+
+        def _fresh(D):
+            return slater.refine_inverse(D, jnp.linalg.inv(D), cfg.ns_steps)
+
         def _refresh(_):
-            su, lu, _, _, mu = slater._spin_block_batched(up, cfg.ns_steps)
-            if cfg.n_dn > 0:
-                sd, ld, _, _, md = slater._spin_block_batched(dn,
-                                                              cfg.ns_steps)
-                return mu, md, su * sd, lu + ld
-            return mu, minv_dn, su, lu
+            return (_fresh(up[..., 0]),
+                    _fresh(dn[..., 0]) if cfg.n_dn > 0 else minv_dn)
 
         def _correct(_):
-            # sign and log|det| come from a batched LU of the sweep's
-            # Slater matrices, not from the sum of the moves' log-ratios:
-            # at |log det| ~ 600 (the paper's 158 electrons) one fp32 ulp is
-            # 6e-5, and ~100 accepted moves per sweep drift that sum past
-            # the 1e-4 contract (DESIGN.md §6).  The LU costs about what
-            # the two Newton–Schulz GEMMs do.
             mu = slater.refine_inverse(up[..., 0], minv_up)
-            su, lu = jnp.linalg.slogdet(up[..., 0])
-            if cfg.n_dn > 0:
-                md = slater.refine_inverse(dn[..., 0], minv_dn)
-                sd, ld = jnp.linalg.slogdet(dn[..., 0])
-                return mu, md, su * sd, lu + ld
-            return mu, minv_dn, su, lu
+            md = (slater.refine_inverse(dn[..., 0], minv_dn)
+                  if cfg.n_dn > 0 else minv_dn)
+            return mu, md
 
-        minv_up, minv_dn, sign, logdet = jax.lax.cond(
+        minv_up, minv_dn = jax.lax.cond(
             sweeps % cfg.sem_refresh == 0, _refresh, _correct, None)
 
         ens_new = _energy_ensemble(cfg, params, r, Cw, minv_up, minv_dn,

@@ -196,7 +196,7 @@ def _mo_tensor(cfg: WavefunctionConfig, params: WavefunctionParams,
     if _screening_active(cfg):
         return _mo_tensor_screened(cfg, params, r_elec)
     B, atom_active = aos.eval_ao_block(cfg.basis, params.coords, r_elec)
-    ao_mask = atom_active[:, jnp.asarray(cfg.basis.ao_atom)]
+    ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)
     count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)
     if _mo_product_method(cfg) == 'kernel':
         from repro.kernels.sparse_mo.ops import sparse_mo_products
@@ -238,7 +238,7 @@ def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
         return (jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0),
                 count.reshape(W, n_e))
     Bw, atom_active = aos.eval_ao_block(cfg.basis, params.coords, R)
-    ao_mask = atom_active[..., jnp.asarray(cfg.basis.ao_atom)]  # (W, n_e, ao)
+    ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)    # (W, n_e, ao)
     count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)         # (W, n_e)
     n_rows = params.mo.shape[0]
 

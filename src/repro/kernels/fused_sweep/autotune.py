@@ -95,15 +95,12 @@ def _measure(n_e: int, W: int, candidates, repeats: int = 2) -> int:
     en = 0.01 * jax.random.normal(ks[4], (W, n_up), jnp.float32)
     logu = jnp.log(jax.random.uniform(ks[5], (W, n_up),
                                       minval=1e-6, maxval=1.0))
-    sign = jnp.ones((W,), jnp.float32)
-    logdet = jnp.zeros((W,), jnp.float32)
 
     best, best_t = None, float('inf')
     for tile_w in candidates:
         def _run():
             out = fused_sweep_block(
-                minv, phi, r, r_prop, en, logu, sign, logdet,
-                jnp.float32(1.0), offset=0, n_up=n_up, use_kernel=True,
+                minv, phi, r, r_prop, en, logu, jnp.float32(1.0), offset=0, n_up=n_up, use_kernel=True,
                 tile_w=tile_w)
             jax.block_until_ready(out)
         _run()                                       # compile/warmup

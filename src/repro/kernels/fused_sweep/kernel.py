@@ -14,7 +14,7 @@ that split is exact).
 Tile layout: a single grid dimension over walker tiles; each grid step
 owns a ``(tile_w, ...)`` slice of every walker-major operand and loops
 over the block's electrons with ``fori_loop``, carrying the evolving
-``(r, minv, sign, logdet, P, rdet)`` state in registers/VMEM and calling
+``(r, minv, P, rdet)`` state in registers/VMEM and calling
 the SAME ``ref._move_step`` math as the scan oracle — kernel-vs-ref
 parity is bitwise by construction.  Excitation lists / CI coefficients /
 the e-e Padé denominator are tiny replicated operands (every grid step
@@ -41,23 +41,19 @@ from .ref import _move_step, take
 def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
     """Kernel body: unpack tile refs, loop the sweep, write final state."""
     if multidet:
-        (minv_ref, phi_ref, r_ref, rp_ref, en_ref, logu_ref, sign_ref,
-         logdet_ref, bee_ref, p_ref, rdet_ref, roth_ref, holes_ref,
-         parts_ref, coeffs_ref,
-         minv_out, r_out, sign_out, logdet_out, acc_out, p_out,
-         rdet_out) = refs
+        (minv_ref, phi_ref, r_ref, rp_ref, en_ref, logu_ref, bee_ref,
+         p_ref, rdet_ref, roth_ref, holes_ref, parts_ref, coeffs_ref,
+         minv_out, r_out, acc_out, p_out, rdet_out) = refs
         ci_args = (holes_ref[...], parts_ref[...], coeffs_ref[...],
                    roth_ref[...])
         P0, rdet0 = p_ref[...], rdet_ref[...]
     else:
-        (minv_ref, phi_ref, r_ref, rp_ref, en_ref, logu_ref, sign_ref,
-         logdet_ref, bee_ref,
-         minv_out, r_out, sign_out, logdet_out, acc_out) = refs
+        (minv_ref, phi_ref, r_ref, rp_ref, en_ref, logu_ref, bee_ref,
+         minv_out, r_out, acc_out) = refs
         # no CI state: None leaves keep zero-size vectors out of the body
         ci_args = P0 = rdet0 = None
 
     b_ee = bee_ref[0, 0]
-    sign0, logdet0 = sign_ref[...][:, 0], logdet_ref[...][:, 0]
     phi = phi_ref[...]                      # (tw, n_blk, n_cols)
     rp = rp_ref[...]                        # (tw, n_blk, 3)
     en = en_ref[...]                        # (tw, n_blk)
@@ -76,13 +72,11 @@ def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
         acc = jnp.where(move == e, accept[:, None].astype(acc.dtype), acc)
         return state, acc
 
-    state0 = (r_ref[...], minv_ref[...], sign0, logdet0, P0, rdet0)
-    (r, minv, sign, logdet, P, rdet), acc = jax.lax.fori_loop(
+    state0 = (r_ref[...], minv_ref[...], P0, rdet0)
+    (r, minv, P, rdet), acc = jax.lax.fori_loop(
         0, n_blk, _step, (state0, acc0))
     minv_out[...] = minv
     r_out[...] = r
-    sign_out[...] = sign[:, None]
-    logdet_out[...] = logdet[:, None]
     acc_out[...] = acc
     if multidet:
         p_out[...] = P
@@ -92,15 +86,15 @@ def _body(refs, *, n_blk, offset, n_up, n_occ, n_e_valid, multidet):
 @functools.partial(jax.jit, static_argnames=('offset', 'n_up', 'n_occ',
                                              'n_e_valid', 'tile_w',
                                              'interpret'))
-def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
-                     b_ee, ci_ops=None, *, offset, n_up, n_occ, n_e_valid,
+def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, b_ee,
+                     ci_ops=None, *, offset, n_up, n_occ, n_e_valid,
                      tile_w=8, interpret):
     """Raw kernel dispatch on pre-padded walker-major operands.
 
     Args:
       minv: (W, n, n) f32, W a multiple of ``tile_w``.
       phi: (W, n_blk, n_cols); r: (W, n_e, 3); r_prop: (W, n_blk, 3);
-      en_delta/logu: (W, n_blk); sign/logdet: (W, 1); b_ee: (1, 1).
+      en_delta/logu: (W, n_blk); b_ee: (1, 1).
       ci_ops: None or (P (W, n_orb, n_occ), rdet (W, n_det),
         r_other (W, n_det), holes (n_det, k) i32, parts, coeffs (n_det,)).
       offset/n_up/n_occ/n_e_valid: static block geometry (true sizes under
@@ -108,8 +102,7 @@ def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
       interpret: run the Python interpreter backend (off the TPU; see
         ``kernels.platform.kernel_plan``); False compiles for the TPU.
 
-    Returns (minv, r, sign (W, 1), logdet (W, 1), acc (W, n_blk) f32
-    [, P, rdet]).
+    Returns (minv, r, acc (W, n_blk) f32 [, P, rdet]).
     """
     W, n, _ = minv.shape
     n_e = r.shape[1]
@@ -125,15 +118,12 @@ def fused_sweep_call(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
         return pl.BlockSpec(tuple(block), lambda w: (0,) * len(block))
 
     in_specs = [_w(n, n), _w(n_blk, n_cols), _w(n_e, 3), _w(n_blk, 3),
-                _w(n_blk), _w(n_blk), _w(1), _w(1), _rep(1, 1)]
-    out_specs = [_w(n, n), _w(n_e, 3), _w(1), _w(1), _w(n_blk)]
+                _w(n_blk), _w(n_blk), _rep(1, 1)]
+    out_specs = [_w(n, n), _w(n_e, 3), _w(n_blk)]
     out_shape = [jax.ShapeDtypeStruct((W, n, n), minv.dtype),
                  jax.ShapeDtypeStruct((W, n_e, 3), r.dtype),
-                 jax.ShapeDtypeStruct((W, 1), sign.dtype),
-                 jax.ShapeDtypeStruct((W, 1), logdet.dtype),
                  jax.ShapeDtypeStruct((W, n_blk), jnp.float32)]
-    operands = [minv, phi, r, r_prop, en_delta, logu, sign, logdet,
-                jnp.asarray(b_ee, jnp.float32).reshape(1, 1)]
+    operands = [minv, phi, r, r_prop, en_delta, logu, jnp.asarray(b_ee, jnp.float32).reshape(1, 1)]
     multidet = ci_ops is not None
     if multidet:
         P, rdet, r_other, holes, parts, coeffs = ci_ops

@@ -34,8 +34,8 @@ def _pad_axis(x: jnp.ndarray, axis: int, mult: int, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
-                      b_ee, ci_ops=None, *, offset, n_up, use_kernel=False,
+def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, b_ee,
+                      ci_ops=None, *, offset, n_up, use_kernel=False,
                       tile_w=8):
     """One spin block's fused sweep: scan oracle or Pallas kernel.
 
@@ -43,7 +43,7 @@ def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
       minv: (W, n, n) f32 maintained inverse of THIS block.
       phi: (W, n_blk, n_cols) proposal MO values (full orbital panel when
         multidet); r: (W, n_e, 3) current positions (both blocks);
-      r_prop: (W, n_blk, 3); en_delta/logu: (W, n_blk); sign/logdet: (W,).
+      r_prop: (W, n_blk, 3); en_delta/logu: (W, n_blk).
       b_ee: () e-e Padé denominator.
       ci_ops: None or (P, rdet, r_other, holes, parts, coeffs).
       offset/n_up: static block geometry; use_kernel/tile_w: static
@@ -51,23 +51,22 @@ def fused_sweep_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
         mode and, on the TPU, shrinks ``tile_w`` to fit VMEM or refuses the
         width.
 
-    Returns (r, minv, sign, logdet, P, rdet, accept) with accept a
+    Returns (r, minv, P, rdet, accept) with accept a
     (W, n_blk) bool matrix (move-for-move Metropolis outcomes).
     """
     if not use_kernel:
-        return _ref_block(minv, phi, r, r_prop, en_delta, logu, sign,
-                          logdet, b_ee, ci_ops, offset=offset, n_up=n_up)
+        return _ref_block(minv, phi, r, r_prop, en_delta, logu, b_ee, ci_ops,
+                          offset=offset, n_up=n_up)
     n_det = 0 if ci_ops is None else ci_ops[1].shape[-1]
     plan = kernel_plan('fused_sweep', (tile_w,), n=minv.shape[-1],
                        n_e=r.shape[1], n_det=n_det)
-    return _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet,
-                         b_ee, ci_ops, offset=offset, n_up=n_up,
+    return _kernel_block(minv, phi, r, r_prop, en_delta, logu, b_ee, ci_ops, offset=offset, n_up=n_up,
                          tile_w=plan.tiles[0], interpret=plan.interpret)
 
 
 @functools.partial(jax.jit, static_argnames=('offset', 'n_up'))
-def _ref_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
-               ci_ops, *, offset, n_up):
+def _ref_block(minv, phi, r, r_prop, en_delta, logu, b_ee, ci_ops, *,
+               offset, n_up):
     P = rdet = None
     ci_args = None
     if ci_ops is not None:
@@ -75,16 +74,16 @@ def _ref_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
         ci_args = (jnp.asarray(holes, jnp.int32),
                    jnp.asarray(parts, jnp.int32),
                    jnp.asarray(coeffs, jnp.float32), r_other)
-    (r, minv, sign, logdet, P, rdet), acc = fused_sweep_ref(
-        r, minv, sign, logdet, phi, r_prop, en_delta, logu, b_ee,
-        offset=offset, n_up=n_up, P=P, rdet=rdet, ci_args=ci_args)
-    return r, minv, sign, logdet, P, rdet, acc
+    (r, minv, P, rdet), acc = fused_sweep_ref(
+        r, minv, phi, r_prop, en_delta, logu, b_ee, offset=offset,
+        n_up=n_up, P=P, rdet=rdet, ci_args=ci_args)
+    return r, minv, P, rdet, acc
 
 
 @functools.partial(jax.jit, static_argnames=('offset', 'n_up', 'tile_w',
                                              'interpret'))
-def _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
-                  ci_ops, *, offset, n_up, tile_w, interpret):
+def _kernel_block(minv, phi, r, r_prop, en_delta, logu, b_ee, ci_ops, *,
+                  offset, n_up, tile_w, interpret):
     W, n, _ = minv.shape
     n_e = r.shape[1]
     # the TPU wants the trailing two block dims of the inverse and the
@@ -94,8 +93,7 @@ def _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
     lane = 1 if interpret else 128
     minv_p = _pad_axis(_pad_axis(minv, 1, lane), 2, lane)
     phi_p = _pad_axis(phi, 2, lane)
-    args = [minv_p, phi_p, r, r_prop, en_delta, logu, sign[:, None],
-            logdet[:, None]]
+    args = [minv_p, phi_p, r, r_prop, en_delta, logu]
     args = [_pad_axis(a, 0, tile_w) for a in args]
     # padded walkers must never accept: +1e30 beats any finite log-ratio
     args[5] = _pad_axis(logu, 0, tile_w, value=1e30)
@@ -109,14 +107,14 @@ def _kernel_block(minv, phi, r, r_prop, en_delta, logu, sign, logdet, b_ee,
                            n_e_valid=n_e, tile_w=tile_w,
                            interpret=interpret)
     if ci_ops is not None:
-        minv_o, r_o, sign_o, logdet_o, acc, P_o, rdet_o = out
+        minv_o, r_o, acc, P_o, rdet_o = out
         P_o, rdet_o = P_o[:W], rdet_o[:W]
     else:
-        minv_o, r_o, sign_o, logdet_o, acc = out
+        minv_o, r_o, acc = out
         P_o = jnp.zeros((W, 0, 0), minv.dtype)
         rdet_o = jnp.zeros((W, 0), minv.dtype)
-    return (r_o[:W], minv_o[:W, :n, :n], sign_o[:W, 0], logdet_o[:W, 0],
-            P_o, rdet_o, acc[:W].astype(bool))
+    return (r_o[:W], minv_o[:W, :n, :n], P_o, rdet_o,
+            acc[:W].astype(bool))
 
 
 __all__ = ['fused_sweep_block', 'fused_sweep_ref']

@@ -83,7 +83,7 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
     construction.
 
     Args:
-      state: (r, minv, sign, logdet, P, rdet) — P/rdet are zero-size
+      state: (r, minv, P, rdet) — P/rdet are zero-size
         arrays (scan) or None (kernel) in the single-determinant case.
       e: block-local electron index (traced).
       phi_e: (W, n_cols) proposal MO values (occupied panel = [:, :n_occ];
@@ -98,7 +98,7 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
 
     Returns (new_state, accept (W,) bool).
     """
-    r, minv, sign, logdet, P, rdet = state
+    r, minv, P, rdet = state
     j = offset + e
     r_old = take(r, j)                                    # (W, 3)
     # lanes past n_occ are the zero padding of minv and phi (the CI panel's
@@ -140,23 +140,20 @@ def _move_step(state, e, phi_e, rp_e, en_e, logu_e, b_ee, *, offset, n_up,
     r_sel = jnp.where(accept[:, None], rp_e, r_old)       # (W, 3)
     ri = jax.lax.broadcasted_iota(jnp.int32, r.shape, 1)
     r = jnp.where(ri == j, r_sel[:, None, :], r)
-    logdet = logdet + jnp.where(accept, log_ratio, 0.0)
-    sign = sign * jnp.where(accept, jnp.sign(ratio), 1.0)
     if ci_args is not None:
         P = jnp.where(accept[:, None, None],
                       P - g_vec[:, :, None] * row[:, None, :n_occ], P)
         rdet = jnp.where(accept[:, None], rdet_new, rdet)
-    return (r, minv, sign, logdet, P, rdet), accept
+    return (r, minv, P, rdet), accept
 
 
-def fused_sweep_ref(r, minv, sign, logdet, phi, r_prop, en_delta, logu,
-                    b_ee, *, offset, n_up, n_occ=None, n_e_valid=None,
+def fused_sweep_ref(r, minv, phi, r_prop, en_delta, logu, b_ee, *, offset, n_up, n_occ=None, n_e_valid=None,
                     P=None, rdet=None, ci_args=None):
     """One spin block's whole sweep as a single scan — the fused oracle.
 
     Args:
       r: (W, n_e, 3) current positions (BOTH spin blocks — the e-e Jastrow
-        delta needs them); minv: (W, n, n); sign/logdet: (W,).
+        delta needs them); minv: (W, n, n).
       phi: (W, n_blk, n_cols) precomputed proposal MO values.
       r_prop: (W, n_blk, 3) precomputed proposals; en_delta/logu:
         (W, n_blk) precomputed e-n Jastrow deltas / log-uniform draws.
@@ -166,7 +163,7 @@ def fused_sweep_ref(r, minv, sign, logdet, phi, r_prop, en_delta, logu,
         (default: unpadded sizes).
       P/rdet + ci_args=(holes, parts, coeffs, r_other): multidet state.
 
-    Returns ((r, minv, sign, logdet, P, rdet), accept (W, n_blk) bool).
+    Returns ((r, minv, P, rdet), accept (W, n_blk) bool).
     """
     W, n_blk = r_prop.shape[:2]
     if n_occ is None:
@@ -184,6 +181,5 @@ def fused_sweep_ref(r, minv, sign, logdet, phi, r_prop, en_delta, logu,
                           n_up=n_up, n_occ=n_occ, n_e_valid=n_e_valid,
                           ci_args=ci_args)
 
-    state, acc = jax.lax.scan(_move, (r, minv, sign, logdet, P, rdet),
-                              jnp.arange(n_blk))
+    state, acc = jax.lax.scan(_move, (r, minv, P, rdet), jnp.arange(n_blk))
     return state, acc.T                                   # (W, n_blk)

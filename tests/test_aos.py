@@ -94,3 +94,18 @@ def test_active_indices_and_pack_roundtrip():
         jnp.where(valid[..., None], Bp, 0.0))
     np.testing.assert_array_equal(np.asarray(B_rec), np.asarray(B))
     assert bool(jnp.all(count <= basis.n_ao))
+
+
+@pytest.mark.parametrize('batch', [(), (5,), (2, 5)], ids=['one', 'flat', 'walkers'])
+def test_atom_to_ao_matches_gather(batch):
+    """The gather-free per-atom -> per-AO expansion equals indexing by
+    ``ao_atom``, bit for bit, for floats and masks of any batch shape."""
+    basis, _ = _random_basis(3)
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=batch + (3,)), jnp.float32)
+    ao_atom = np.asarray(basis.ao_atom)
+    np.testing.assert_array_equal(np.asarray(aos.atom_to_ao(x, ao_atom)),
+                                  np.asarray(x)[..., ao_atom])
+    m = x > 0
+    np.testing.assert_array_equal(np.asarray(aos.atom_to_ao(m, ao_atom)),
+                                  np.asarray(m)[..., ao_atom])

@@ -4,8 +4,8 @@
 single dispatch — ``ref.fused_sweep_ref`` as one ``lax.scan``, ``kernel.
 fused_sweep_call`` as one walker-tiled Pallas call.  Both paths execute
 the SAME ``ref._move_step`` per electron, so the kernel must reproduce
-the oracle MOVE-FOR-MOVE BITWISE at fp32: positions, inverse, sign,
-logdet and the full accept matrix — including ragged walker tiles (W not
+the oracle MOVE-FOR-MOVE BITWISE at fp32: positions, inverse and the
+full accept matrix — including ragged walker tiles (W not
 a multiple of tile_w: padded walkers carry logu=+1e30 and never accept),
 degenerate all-reject / all-accept sweeps, multidet (n_det > 1) P-table
 updates, and under an 8-virtual-device walker mesh.
@@ -77,14 +77,14 @@ def _run_both(cfg, params, ens, tile_w, logu_override=None, ci_ops=None,
     outs = {}
     for use_kernel in (False, True):
         outs[use_kernel] = fused_sweep_block(
-            ens.minv_up, phi, ens.r, r_prop, en_delta, logu, ens.sign,
-            ens.logdet, params.jastrow.b_ee, ci_ops, offset=0,
+            ens.minv_up, phi, ens.r, r_prop, en_delta, logu,
+            params.jastrow.b_ee, ci_ops, offset=0,
             n_up=cfg.n_up, use_kernel=use_kernel, tile_w=tile_w)
     return outs[False], outs[True], r_prop
 
 
 def _assert_bitwise(ref_out, ker_out):
-    names = ('r', 'minv', 'sign', 'logdet', 'P', 'rdet', 'accept')
+    names = ('r', 'minv', 'P', 'rdet', 'accept')
     for name, a, b in zip(names, ref_out, ker_out):
         assert a.shape == b.shape, name
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
@@ -100,7 +100,7 @@ def test_kernel_matches_ref_bitwise(water_wf):
     cfg, params = water_wf
     ens = _state(cfg, params, W=8)
     ref_out, ker_out, _ = _run_both(cfg, params, ens, tile_w=4)
-    assert bool(np.any(np.asarray(ref_out[6]))), 'sweep accepted nothing'
+    assert bool(np.any(np.asarray(ref_out[4]))), 'sweep accepted nothing'
     _assert_bitwise(ref_out, ker_out)
 
 
@@ -112,7 +112,7 @@ def test_ragged_walker_tiles(water_wf, tile_w):
     ens = _state(cfg, params, W=5)
     ref_out, ker_out, _ = _run_both(cfg, params, ens, tile_w=tile_w)
     assert ker_out[0].shape == (5, cfg.n_elec, 3)
-    assert ker_out[6].shape == (5, cfg.n_up)
+    assert ker_out[4].shape == (5, cfg.n_up)
     _assert_bitwise(ref_out, ker_out)
 
 
@@ -124,13 +124,11 @@ def test_all_reject_sweep(water_wf):
     ref_out, ker_out, _ = _run_both(cfg, params, ens, tile_w=4,
                                     logu_override=1e30)
     for out in (ref_out, ker_out):
-        assert not np.any(np.asarray(out[6]))
+        assert not np.any(np.asarray(out[4]))
         np.testing.assert_array_equal(np.asarray(out[0]),
                                       np.asarray(ens.r))
         np.testing.assert_array_equal(np.asarray(out[1]),
                                       np.asarray(ens.minv_up))
-        np.testing.assert_array_equal(np.asarray(out[3]),
-                                      np.asarray(ens.logdet))
     _assert_bitwise(ref_out, ker_out)
 
 
@@ -142,7 +140,7 @@ def test_all_accept_sweep(water_wf):
     ref_out, ker_out, r_prop = _run_both(cfg, params, ens, tile_w=4,
                                          logu_override=-1e30)
     for out in (ref_out, ker_out):
-        assert np.all(np.asarray(out[6]))
+        assert np.all(np.asarray(out[4]))
         np.testing.assert_array_equal(np.asarray(out[0][:, :cfg.n_up]),
                                       np.asarray(r_prop))
     _assert_bitwise(ref_out, ker_out)
@@ -158,7 +156,7 @@ def test_multidet_kernel_parity():
               ci.parts_up, ci.coeffs)
     ref_out, ker_out, _ = _run_both(cfg, params, ens, tile_w=4,
                                     ci_ops=ci_ops)
-    assert ref_out[4].shape[1] > 0 and ref_out[5].shape[1] == 4
+    assert ref_out[2].shape[1] > 0 and ref_out[3].shape[1] == 4
     _assert_bitwise(ref_out, ker_out)
 
 

@@ -271,9 +271,8 @@ def test_sem_sweep_smw_ratios_track_fresh_slogdet(water_ci):
     ens = sem.evaluate_sem(cfg, params, r)
     wkeys = Population().walker_keys(jax.random.PRNGKey(9), 6)
     A_up, _ = sem._mo_blocks(cfg, params)
-    carry = (ens.r, ens.minv_up, ens.sign, ens.logdet, ens.p_up,
-             ens.rdet_up)
-    (r2, minv_up, sign, logdet, P, rdet), acc = sem._sweep_spin_block(
+    carry = (ens.r, ens.minv_up, ens.p_up, ens.rdet_up)
+    (r2, minv_up, P, rdet), acc = sem._sweep_spin_block(
         cfg, params, A_up, 0, cfg.n_up, wkeys, 0.4, carry,
         ci_args=(ci.holes_up, ci.parts_up, ens.rdet_dn))
     assert np.any(np.asarray(r2) != np.asarray(ens.r)), 'no move accepted'

@@ -180,20 +180,17 @@ def test_spin_boundary_electron_mixed_precision(water_wf, precision):
     assert ens.minv_dn.dtype == slater.storage_dtype(precision)
     wkeys = Population().walker_keys(jax.random.PRNGKey(5), 4)
     _, A_dn = sem._mo_blocks(cfg, params)
-    carry = (ens.r, sem._to_compute(ens.minv_dn, cfg), ens.sign, ens.logdet)
-    (r2, minv_dn, sign, logdet), _ = sem._sweep_spin_block(
+    carry = (ens.r, sem._to_compute(ens.minv_dn, cfg))
+    (r2, minv_dn), _ = sem._sweep_spin_block(
         cfg, params, A_dn, cfg.n_up, 1, wkeys, 0.5, carry)
     assert np.any(np.asarray(r2) != np.asarray(r)), 'no move accepted'
     moved = np.any(np.asarray(r2) != np.asarray(r), axis=-1)
     assert not np.any(np.delete(moved, cfg.n_up, axis=1))
     fresh = evaluate_sem(dataclasses.replace(cfg, precision='fp32'),
                          params, r2)
-    rel, abs_ld = slater.drift_tolerance(precision)
+    rel, _ = slater.drift_tolerance(precision)
     scale = max(np.max(np.abs(_f64(fresh.minv_dn))), 1.0)
     assert np.max(np.abs(_f64(minv_dn) - _f64(fresh.minv_dn))) / scale <= rel
-    np.testing.assert_allclose(np.asarray(logdet),
-                               np.asarray(fresh.logdet), atol=abs_ld)
-    np.testing.assert_array_equal(np.asarray(sign), np.asarray(fresh.sign))
 
 
 # ---------------------------------------------------------------------------
@@ -272,7 +269,7 @@ def _sweep_jaxpr(cfg, params, path):
                     k, 0.4))(ens, wkeys)
         else:
             A_up, _ = sem._mo_blocks(cfg, params)
-            carry = (ens.r, ens.minv_up, ens.sign, ens.logdet)
+            carry = (ens.r, ens.minv_up)
             jx = jax.make_jaxpr(
                 lambda c, k: sem._sweep_spin_block(
                     cfg, params, A_up, 0, cfg.n_up, k, 0.4, c))(carry, wkeys)

@@ -108,14 +108,13 @@ def test_multidet_ratio_compiles(one_chip, tpu_plan, system):
 def _fused(n, n_e):
     from repro.kernels.fused_sweep.ops import fused_sweep_block
 
-    def fn(minv, phi, r, r_prop, en, logu, sign, logdet):
-        return fused_sweep_block(minv, phi, r, r_prop, en, logu, sign,
-                                 logdet, jnp.float32(1.0), offset=0, n_up=n,
+    def fn(minv, phi, r, r_prop, en, logu):
+        return fused_sweep_block(minv, phi, r, r_prop, en, logu,
+                                 jnp.float32(1.0), offset=0, n_up=n,
                                  use_kernel=True, tile_w=8)
 
     shapes = (((W, n, n), F32), ((W, n, n), F32), ((W, n_e, 3), F32),
-              ((W, n, 3), F32), ((W, n), F32), ((W, n), F32), ((W,), F32),
-              ((W,), F32))
+              ((W, n, 3), F32), ((W, n), F32), ((W, n), F32))
     return fn, shapes
 
 
@@ -151,6 +150,25 @@ def test_plan_interprets_off_tpu_and_compiles_on_tpu(kernel, tiles, dims):
     assert not tpu.interpret
     # the default platform here is the CPU
     assert kernel_plan(kernel, tiles, **dims).interpret
+
+
+def test_mo_tensor_pass_has_no_walker_batched_gather(one_chip):
+    """The ensemble MO tensor pass at `smallest` with 64 walkers compiles
+    for a v5e without gathering a walker-batched operand by constant
+    indices: that gather (the AO power table, ``aos._monomial_1d``) halted
+    the chip.  Only the sparse product's data-dependent gathers remain."""
+    import re
+    from repro.core.wavefunction import _mo_tensor_ensemble
+    from repro.systems import build_system
+    cfg, params = build_system('smallest')
+    ps = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                     sharding=one_chip),
+                      params)
+    R = jax.ShapeDtypeStruct((W, cfg.n_elec, 3), F32, sharding=one_chip)
+    text = jax.jit(lambda p, r: _mo_tensor_ensemble(cfg, p, r)[0]).lower(
+        ps, R).compile().as_text()
+    batched = re.findall(rf'gather\(.*slice_sizes={{{W},', text)
+    assert not batched, batched[:2]
 
 
 def test_plan_tpu_tiles_are_aligned():
