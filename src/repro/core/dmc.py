@@ -57,7 +57,7 @@ class DMCPropagator:
     restarts re-evaluate reservoir walkers via ``restart_ensemble``.
     """
 
-    aux_fields = ('accept', 'pop_weight', 'sign_flips')
+    aux_fields = ('accept', 'pop_weight', 'sign_flips', 'ao_fill')
 
     def __init__(self, cfg: WavefunctionConfig, e_trial: float,
                  tau: float = 0.02, window: int = 20, damping: float = 0.5,
@@ -86,8 +86,8 @@ class DMCPropagator:
         """One DMC generation: move, branch weights, reconfigure."""
         ens = state.ens
         kp, kr = jax.random.split(key)
-        new, log_ratio, u = propose_diffusion(self.cfg, params, ens, kp,
-                                              pop, self.tau)
+        new, log_ratio, u, st = propose_diffusion(self.cfg, params, ens,
+                                                  kp, pop, self.tau)
         crossed = new.sign * ens.sign < 0      # fixed-node: reject crossings
         accept = (jnp.log(u) < log_ratio) & ~crossed
         moved = merge_accepted(new, ens, accept)
@@ -105,29 +105,33 @@ class DMCPropagator:
         w = jnp.clip(w, 0.0, 4.0)              # guard rare E_L spikes
 
         # global reconfiguration: identical resampling for any mesh shape
-        idx = reconfigure(kr, pop.gather(w))
-        moved_all = jax.tree.map(pop.gather, moved)
-        idx_local = pop.take_local(idx, ens.r.shape[0])
-        ens_next = jax.tree.map(lambda a: a[idx_local], moved_all)
+        with jax.named_scope('qmc.reconfig'):
+            idx = reconfigure(kr, pop.gather(w))
+            moved_all = jax.tree.map(pop.gather, moved)
+            idx_local = pop.take_local(idx, ens.r.shape[0])
+            ens_next = jax.tree.map(lambda a: a[idx_local], moved_all)
 
         mean_w = pop.mean(w)
         log_hist, g_weight = global_weight_update(state.log_w_hist, mean_w)
+        # active AOs per electron of the proposals, which every generation
+        # evaluates (VMC and SEM read the block's last configuration)
+        fill = pop.mean(st.ao_count.astype(jnp.float32))
         out = (pop.mean(moved.e_loc), g_weight, acc_frac,
-               pop.mean(crossed.astype(jnp.float32)), mean_w)
+               pop.mean(crossed.astype(jnp.float32)), mean_w, fill)
         return DMCState(ens=ens_next, log_w_hist=log_hist,
                         e_trial=state.e_trial), out
 
     def block_stats(self, params, state: DMCState, outs,
                     pop: Population) -> DriverStats:
         """Global-weight-weighted mixed estimator over the block."""
-        e, gw, acc, cross, w = outs            # (steps,) replicated scalars
+        e, gw, acc, cross, w, fill = outs      # (steps,) replicated scalars
         wsum = jnp.sum(gw)
         return DriverStats(
             weight=wsum,
             e_mean=jnp.sum(gw * e) / wsum,
             e2_mean=jnp.sum(gw * e ** 2) / wsum,
             aux=dict(accept=jnp.mean(acc), pop_weight=jnp.mean(w),
-                     sign_flips=jnp.mean(cross)))
+                     sign_flips=jnp.mean(cross), ao_fill=jnp.mean(fill)))
 
     def feedback(self, state: DMCState, e_estimate) -> DMCState:
         """Between-block E_T update (routed through ``update_e_trial``)."""

@@ -279,7 +279,10 @@ class EnsembleDriver:
                 params, st, jax.random.fold_in(key, i), pop)
 
         state, outs = jax.lax.scan(_body, state, jnp.arange(self.steps))
-        return state, self.propagator.block_stats(params, state, outs, pop)
+        # a phase, not a layer: its ops also carry the layer they run in
+        with jax.named_scope('qmc.block_stats'):
+            return state, self.propagator.block_stats(params, state, outs,
+                                                      pop)
 
     def _build(self, state):
         donate = (1,) if self.donate else ()

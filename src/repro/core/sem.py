@@ -184,6 +184,7 @@ def _empty_ci_state(W, dtype):
             jnp.zeros((W, 0), dtype), jnp.zeros((W, 0), dtype))
 
 
+@jax.named_scope('qmc.jastrow_energy')
 def _energy_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
                      R, Cw, minv_up, minv_dn, sign, logdet) -> SEMEnsemble:
     """Assemble the SEM ensemble from maintained inverses (no inversion).
@@ -268,16 +269,18 @@ def evaluate_sem(cfg: WavefunctionConfig, params: WavefunctionParams,
     W = R.shape[0]
     Cw, _ = _mo_tensor_ensemble(cfg, params, R)
     up, dn = _slater_blocks(cfg, Cw)
-    su, lu, _, _, mu = slater._spin_block_batched(up, cfg.ns_steps)
-    if cfg.n_dn > 0:
-        sd, ld, _, _, md = slater._spin_block_batched(dn, cfg.ns_steps)
-        sign, logdet = su * sd, lu + ld
-    else:
-        sign, logdet = su, lu
-        md = jnp.zeros((W, 0, 0), Cw.dtype)
+    with jax.named_scope('qmc.slater'):
+        su, lu, _, _, mu = slater._spin_block_batched(up, cfg.ns_steps)
+        if cfg.n_dn > 0:
+            sd, ld, _, _, md = slater._spin_block_batched(dn, cfg.ns_steps)
+            sign, logdet = su * sd, lu + ld
+        else:
+            sign, logdet = su, lu
+            md = jnp.zeros((W, 0, 0), Cw.dtype)
     return _energy_ensemble(cfg, params, R, Cw, mu, md, sign, logdet)
 
 
+@jax.named_scope('qmc.sem_move')
 def _sweep_spin_block(cfg, params, A_blk, offset, n_blk, wkeys, step_size,
                       carry, ci_args=None):
     """One Metropolis trial per electron of one spin block, all walkers.
@@ -478,6 +481,7 @@ def _fused_phi_all(cfg, params, A_up, A_dn, r_prop):
     return phi_up, phi_dn
 
 
+@jax.named_scope('qmc.sem_move')
 def _fused_sweeps(cfg, params, ens, minv_up, minv_dn, p_up, p_dn, wkeys,
                   step_size):
     """Both spin blocks' sweeps through the fused path (DESIGN.md §13).
@@ -634,17 +638,6 @@ class SEMVMCPropagator:
         up, dn = _slater_blocks(cfg, Cw)
         sweeps = state.sweeps + 1
 
-        # sign and log|det| come from a batched LU of the sweep's Slater
-        # matrices, not from a running sum of the moves' log-ratios: at
-        # |log det| ~ 600 (the paper's 158 electrons) one fp32 ulp is 6e-5,
-        # and ~100 accepted moves per sweep drift such a sum past the 1e-4
-        # contract (DESIGN.md §6).  Only the inverse is maintained: Newton–
-        # Schulz corrected every sweep, rebuilt every ``sem_refresh``.
-        sign, logdet = jnp.linalg.slogdet(up[..., 0])
-        if cfg.n_dn > 0:
-            sd, ld = jnp.linalg.slogdet(dn[..., 0])
-            sign, logdet = sign * sd, logdet + ld
-
         def _fresh(D):
             return slater.refine_inverse(D, jnp.linalg.inv(D), cfg.ns_steps)
 
@@ -658,8 +651,19 @@ class SEMVMCPropagator:
                   if cfg.n_dn > 0 else minv_dn)
             return mu, md
 
-        minv_up, minv_dn = jax.lax.cond(
-            sweeps % cfg.sem_refresh == 0, _refresh, _correct, None)
+        # sign and log|det| come from a batched LU of the sweep's Slater
+        # matrices, not from a running sum of the moves' log-ratios: at
+        # |log det| ~ 600 (the paper's 158 electrons) one fp32 ulp is 6e-5,
+        # and ~100 accepted moves per sweep drift such a sum past the 1e-4
+        # contract (DESIGN.md §6).  Only the inverse is maintained: Newton–
+        # Schulz corrected every sweep, rebuilt every ``sem_refresh``.
+        with jax.named_scope('qmc.slater'):
+            sign, logdet = jnp.linalg.slogdet(up[..., 0])
+            if cfg.n_dn > 0:
+                sd, ld = jnp.linalg.slogdet(dn[..., 0])
+                sign, logdet = sign * sd, logdet + ld
+            minv_up, minv_dn = jax.lax.cond(
+                sweeps % cfg.sem_refresh == 0, _refresh, _correct, None)
 
         ens_new = _energy_ensemble(cfg, params, r, Cw, minv_up, minv_dn,
                                    sign, logdet)

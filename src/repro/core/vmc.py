@@ -85,7 +85,8 @@ def propose_diffusion(cfg, params, ens: WalkerEnsemble, key, pop: Population,
 
     Per-walker RNG streams (``pop.walker_keys`` folds the *global* walker
     index) make proposals identical under any walker-axis sharding.
-    Returns (proposed ensemble, Metropolis log-ratio, per-walker uniforms).
+    Returns (proposed ensemble, Metropolis log-ratio, per-walker uniforms,
+    the proposal's ``PsiState``).
     """
     def _draw(k):
         k_eta, k_u = jax.random.split(k)
@@ -94,11 +95,11 @@ def propose_diffusion(cfg, params, ens: WalkerEnsemble, key, pop: Population,
 
     eta, u = jax.vmap(_draw)(pop.walker_keys(key, ens.r.shape[0]))
     r_new = ens.r + tau * ens.drift + jnp.sqrt(tau) * eta
-    new, _ = evaluate_ensemble(cfg, params, r_new)
+    new, st = evaluate_ensemble(cfg, params, r_new)
     log_ratio = (2.0 * (new.log_psi - ens.log_psi)
                  + _log_green(ens.r, r_new, new.drift, tau)
                  - _log_green(r_new, ens.r, ens.drift, tau))
-    return new, log_ratio, u
+    return new, log_ratio, u, st
 
 
 class VMCPropagator:
@@ -120,8 +121,8 @@ class VMCPropagator:
 
     def propagate(self, params, ens: WalkerEnsemble, key, pop: Population):
         """One all-electron drift-diffusion Metropolis generation."""
-        new, log_ratio, u = propose_diffusion(self.cfg, params, ens, key,
-                                              pop, self.tau)
+        new, log_ratio, u, _ = propose_diffusion(self.cfg, params, ens,
+                                                 key, pop, self.tau)
         accept = jnp.log(u) < log_ratio
         merged = merge_accepted(new, ens, accept)
         out = (pop.mean(merged.e_loc), pop.mean(merged.e_loc ** 2),
@@ -146,7 +147,8 @@ class VMCPropagator:
 def vmc_step(cfg, params, ens: WalkerEnsemble, key, tau):
     """One Metropolis generation (single-device, unsharded)."""
     pop = Population()
-    new, log_ratio, u = propose_diffusion(cfg, params, ens, key, pop, tau)
+    new, log_ratio, u, _ = propose_diffusion(cfg, params, ens, key, pop,
+                                             tau)
     accept = jnp.log(u) < log_ratio
     return merge_accepted(new, ens, accept), accept
 

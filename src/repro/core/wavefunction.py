@@ -167,20 +167,23 @@ def _mo_tensor_screened(cfg: WavefunctionConfig,
     """
     from . import screening as scr_mod
     scr = cfg.screening
-    idx, active, count = scr_mod.active_ao_lists(scr, r_elec)
-    Bp = aos.eval_ao_block_screened(cfg.basis, params.coords, r_elec, idx,
-                                    active)
-    if _mo_product_method(cfg) == 'kernel':
-        from repro.kernels.screened_mo.ops import screened_mo_products
-        to, tk, te = cfg.kernel_tiles
-        C = screened_mo_products(params.mo, Bp, idx, active, tile_o=to,
-                                 tile_k=tk, tile_e=te)
-    elif scr.mo_cells is not None:
-        mo_idx, mo_valid = scr_mod.active_mo_lists(scr, r_elec)
-        C = mos.mo_products_screened(params.mo, Bp, idx, mo_idx, mo_valid,
-                                     chunk=chunk)
-    else:
-        C = mos.mo_products_sparse(params.mo, Bp, idx, chunk=chunk)
+    with jax.named_scope('qmc.ao_index'):
+        idx, active, count = scr_mod.active_ao_lists(scr, r_elec)
+    with jax.named_scope('qmc.ao_eval'):
+        Bp = aos.eval_ao_block_screened(cfg.basis, params.coords, r_elec,
+                                        idx, active)
+    with jax.named_scope('qmc.mo_product'):
+        if _mo_product_method(cfg) == 'kernel':
+            from repro.kernels.screened_mo.ops import screened_mo_products
+            to, tk, te = cfg.kernel_tiles
+            C = screened_mo_products(params.mo, Bp, idx, active, tile_o=to,
+                                     tile_k=tk, tile_e=te)
+        elif scr.mo_cells is not None:
+            mo_idx, mo_valid = scr_mod.active_mo_lists(scr, r_elec)
+            C = mos.mo_products_screened(params.mo, Bp, idx, mo_idx,
+                                         mo_valid, chunk=chunk)
+        else:
+            C = mos.mo_products_sparse(params.mo, Bp, idx, chunk=chunk)
     return C, count
 
 
@@ -195,20 +198,25 @@ def _mo_tensor(cfg: WavefunctionConfig, params: WavefunctionParams,
     """
     if _screening_active(cfg):
         return _mo_tensor_screened(cfg, params, r_elec)
-    B, atom_active = aos.eval_ao_block(cfg.basis, params.coords, r_elec)
-    ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)
-    count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)
+    with jax.named_scope('qmc.ao_eval'):
+        B, atom_active = aos.eval_ao_block(cfg.basis, params.coords, r_elec)
+        ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)
+        count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)
     if _mo_product_method(cfg) == 'kernel':
         from repro.kernels.sparse_mo.ops import sparse_mo_products
         to, tk, te = cfg.kernel_tiles
-        return sparse_mo_products(params.mo, B, ao_mask, tile_o=to,
-                                  tile_k=tk, tile_e=te), count
+        with jax.named_scope('qmc.mo_product'):
+            return sparse_mo_products(params.mo, B, ao_mask, tile_o=to,
+                                      tile_k=tk, tile_e=te), count
     if _mo_product_method(cfg) == 'dense' or cfg.k_max <= 0:
-        return mos.mo_products_dense(params.mo, B), count
-    idx, valid, _ = aos.active_ao_indices(cfg.basis, atom_active, cfg.k_max,
-                                          ao_mask=ao_mask)
-    Bp = aos.pack_b(B, idx, valid)
-    return mos.mo_products_sparse(params.mo, Bp, idx), count
+        with jax.named_scope('qmc.mo_product'):
+            return mos.mo_products_dense(params.mo, B), count
+    with jax.named_scope('qmc.ao_index'):
+        idx, valid, _ = aos.active_ao_indices(cfg.basis, atom_active,
+                                              cfg.k_max, ao_mask=ao_mask)
+        Bp = aos.pack_b(B, idx, valid)
+    with jax.named_scope('qmc.mo_product'):
+        return mos.mo_products_sparse(params.mo, Bp, idx), count
 
 
 def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
@@ -237,35 +245,40 @@ def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
             chunk=mos.default_chunk(W * n_e, ensemble=True))
         return (jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0),
                 count.reshape(W, n_e))
-    Bw, atom_active = aos.eval_ao_block(cfg.basis, params.coords, R)
-    ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)    # (W, n_e, ao)
-    count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)         # (W, n_e)
+    with jax.named_scope('qmc.ao_eval'):
+        Bw, atom_active = aos.eval_ao_block(cfg.basis, params.coords, R)
+        ao_mask = aos.atom_to_ao(atom_active, cfg.basis.ao_atom)  # (W,n_e,ao)
+        count = jnp.sum(ao_mask, axis=-1).astype(jnp.int32)       # (W, n_e)
     n_rows = params.mo.shape[0]
 
     if _mo_product_method(cfg) == 'kernel':
         from repro.kernels.sparse_mo.ops import (ensemble_tiles,
                                                  sparse_mo_products)
-        B2 = jnp.moveaxis(Bw, 0, 1).reshape(Bw.shape[1], W * n_e, 5)
         to, tk, te = ensemble_tiles(cfg.kernel_tiles, n_rows, W * n_e,
                                     cap_e=cfg.kernel_ensemble_tile_cap)
-        C = sparse_mo_products(params.mo, B2,
-                               ao_mask.reshape(W * n_e, -1),
-                               tile_o=to, tile_k=tk, tile_e=te)
-        return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
+        with jax.named_scope('qmc.mo_product'):
+            B2 = jnp.moveaxis(Bw, 0, 1).reshape(Bw.shape[1], W * n_e, 5)
+            C = sparse_mo_products(params.mo, B2,
+                                   ao_mask.reshape(W * n_e, -1),
+                                   tile_o=to, tile_k=tk, tile_e=te)
+            return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
     if _mo_product_method(cfg) == 'dense' or cfg.k_max <= 0:
-        Cw = jnp.einsum('oa,waec->woec', params.mo, Bw,
-                        precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32)
+        with jax.named_scope('qmc.mo_product'):
+            Cw = jnp.einsum('oa,waec->woec', params.mo, Bw,
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
         return Cw, count
-    idx, valid, _ = aos.active_ao_indices(
-        cfg.basis, atom_active.reshape(W * n_e, -1), cfg.k_max,
-        ao_mask=ao_mask.reshape(W * n_e, -1))
-    Bp = jax.vmap(aos.pack_b)(Bw, idx.reshape(W, n_e, -1),
-                              valid.reshape(W, n_e, -1))        # (W,n_e,K,5)
-    C = mos.mo_products_sparse(params.mo, Bp.reshape(W * n_e, -1, 5), idx,
-                               chunk=mos.default_chunk(W * n_e,
-                                                       ensemble=True))
-    return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
+    with jax.named_scope('qmc.ao_index'):
+        idx, valid, _ = aos.active_ao_indices(
+            cfg.basis, atom_active.reshape(W * n_e, -1), cfg.k_max,
+            ao_mask=ao_mask.reshape(W * n_e, -1))
+        Bp = jax.vmap(aos.pack_b)(Bw, idx.reshape(W, n_e, -1),
+                                  valid.reshape(W, n_e, -1))    # (W,n_e,K,5)
+    with jax.named_scope('qmc.mo_product'):
+        C = mos.mo_products_sparse(params.mo, Bp.reshape(W * n_e, -1, 5),
+                                   idx, chunk=mos.default_chunk(
+                                       W * n_e, ensemble=True))
+        return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
 
 
 def _slater_blocks(cfg: WavefunctionConfig, C: jnp.ndarray):
@@ -313,35 +326,38 @@ def _finish_state(cfg: WavefunctionConfig, params: WavefunctionParams,
     ``core.multidet`` (same output contract, ``grad``/``lap`` become the
     CI-weighted contractions).
     """
-    if cfg.ci is not None:
-        from . import multidet
-        up_all, dn_all = _ci_blocks(cfg, C)
-        sign, logdet, sgrad, slap = multidet.ci_assemble(
-            cfg.ci, up_all, dn_all, cfg.ns_steps, coeffs=params.ci_coeffs)
-    else:
-        up, dn = _slater_blocks(cfg, C)
-        su, lu, gu, qu, _ = slater._spin_block(up, cfg.ns_steps)
-        if cfg.n_dn > 0:
-            sd, ld, gd, qd, _ = slater._spin_block(dn, cfg.ns_steps)
-            sign = su * sd
-            logdet = lu + ld
-            sgrad = jnp.concatenate([gu, gd], axis=0)
-            slap = jnp.concatenate([qu, qd], axis=0)
+    with jax.named_scope('qmc.slater'):
+        if cfg.ci is not None:
+            from . import multidet
+            up_all, dn_all = _ci_blocks(cfg, C)
+            sign, logdet, sgrad, slap = multidet.ci_assemble(
+                cfg.ci, up_all, dn_all, cfg.ns_steps,
+                coeffs=params.ci_coeffs)
         else:
-            sign, logdet, sgrad, slap = su, lu, gu, qu
+            up, dn = _slater_blocks(cfg, C)
+            su, lu, gu, qu, _ = slater._spin_block(up, cfg.ns_steps)
+            if cfg.n_dn > 0:
+                sd, ld, gd, qd, _ = slater._spin_block(dn, cfg.ns_steps)
+                sign = su * sd
+                logdet = lu + ld
+                sgrad = jnp.concatenate([gu, gd], axis=0)
+                slap = jnp.concatenate([qu, qd], axis=0)
+            else:
+                sign, logdet, sgrad, slap = su, lu, gu, qu
 
-    jas = jastrow_state(params.jastrow, r_elec, params.coords,
-                        params.charges, cfg.n_up)
-    drift = sgrad + jas.grad
-    # lap Psi / Psi = lapD/D + lapJ + |gradJ|^2 + 2 gradJ . gradD/D, per elec
-    lap_psi_ratio = (slap + jas.lap
-                     + jnp.sum(jas.grad * jas.grad, axis=-1)
-                     + 2.0 * jnp.sum(jas.grad * sgrad, axis=-1))
-    e_kin = -0.5 * jnp.sum(lap_psi_ratio)
-    e_pot = potential_energy(r_elec, params.coords, params.charges)
-    return PsiState(sign=sign, log_psi=logdet + jas.value, drift=drift,
-                    e_loc=e_kin + e_pot, e_kin=e_kin, e_pot=e_pot,
-                    ao_count=count)
+    with jax.named_scope('qmc.jastrow_energy'):
+        jas = jastrow_state(params.jastrow, r_elec, params.coords,
+                            params.charges, cfg.n_up)
+        drift = sgrad + jas.grad
+        # lap Psi/Psi = lapD/D + lapJ + |gradJ|^2 + 2 gradJ . gradD/D, per elec
+        lap_psi_ratio = (slap + jas.lap
+                         + jnp.sum(jas.grad * jas.grad, axis=-1)
+                         + 2.0 * jnp.sum(jas.grad * sgrad, axis=-1))
+        e_kin = -0.5 * jnp.sum(lap_psi_ratio)
+        e_pot = potential_energy(r_elec, params.coords, params.charges)
+        return PsiState(sign=sign, log_psi=logdet + jas.value, drift=drift,
+                        e_loc=e_kin + e_pot, e_kin=e_kin, e_pot=e_pot,
+                        ao_count=count)
 
 
 def psi_state(cfg: WavefunctionConfig, params: WavefunctionParams,

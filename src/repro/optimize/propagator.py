@@ -56,8 +56,8 @@ class OptVMCPropagator(VMCPropagator):
 
     def propagate(self, params, ens, key, pop: Population):
         """One Metropolis generation + the four optimization moments."""
-        new, log_ratio, u = propose_diffusion(self.cfg, params, ens, key,
-                                              pop, self.tau)
+        new, log_ratio, u, _ = propose_diffusion(self.cfg, params, ens,
+                                                 key, pop, self.tau)
         accept = jnp.log(u) < log_ratio
         merged = merge_accepted(new, ens, accept)
         vec = traced_vector(self.cfg, params)

@@ -60,6 +60,7 @@ from typing import Iterable
 import numpy as np
 
 from repro.runtime.blocks import BlockResult, RunningAverage
+from repro.tracing import span
 
 SCHEMA_VERSION = 2
 
@@ -286,39 +287,41 @@ class ResultDatabase:
         exactly like the primary-key ``INSERT OR IGNORE``.
         """
         blocks = list(blocks)
-        accepted: list[BlockResult] = []
-        quota_cache: dict[str, int | None] = {}
-        for b in blocks:
-            reason = validate_block(b)
-            if reason is None and self.require_registered \
-                    and not self.known_run(b.run_key):
-                reason = R_UNREGISTERED
-            if reason is None:
-                quota = quota_cache.get(b.run_key, -1)
-                if quota == -1:
-                    quota = self._quota(b.run_key)
-                    quota_cache[b.run_key] = quota
-                if quota and self.n_blocks(b.run_key) + sum(
-                        a.run_key == b.run_key for a in accepted) >= quota:
-                    reason = R_QUOTA
-            if reason is not None:
-                self.rejects[reason] += 1
-                continue
-            accepted.append(b)
-        if not accepted:
-            return 0
-        rows = [(b.run_key, b.job, b.worker_id, b.block_id, b.weight,
-                 b.e_mean, b.e2_mean, json.dumps(dict(b.aux)), b.timestamp)
-                for b in accepted]
-        with self._lock:
-            cur = self._conn.executemany(
-                'INSERT OR IGNORE INTO blocks '
-                'SELECT ?,?,?,?,?,?,?,?,? WHERE NOT EXISTS ('
-                '  SELECT 1 FROM watermarks w WHERE w.run_key=?1 '
-                '  AND w.job=?2 AND w.worker_id=?3 AND w.max_block_id>=?4)',
-                rows)
-            self._conn.commit()
-        return cur.rowcount if cur.rowcount >= 0 else len(rows)
+        with span('qmc.db_append'):
+            accepted: list[BlockResult] = []
+            quota_cache: dict[str, int | None] = {}
+            for b in blocks:
+                reason = validate_block(b)
+                if reason is None and self.require_registered \
+                        and not self.known_run(b.run_key):
+                    reason = R_UNREGISTERED
+                if reason is None:
+                    quota = quota_cache.get(b.run_key, -1)
+                    if quota == -1:
+                        quota = self._quota(b.run_key)
+                        quota_cache[b.run_key] = quota
+                    if quota and self.n_blocks(b.run_key) + sum(
+                            a.run_key == b.run_key for a in accepted) >= quota:
+                        reason = R_QUOTA
+                if reason is not None:
+                    self.rejects[reason] += 1
+                    continue
+                accepted.append(b)
+            if not accepted:
+                return 0
+            rows = [(b.run_key, b.job, b.worker_id, b.block_id, b.weight,
+                     b.e_mean, b.e2_mean, json.dumps(dict(b.aux)), b.timestamp)
+                    for b in accepted]
+            with self._lock:
+                cur = self._conn.executemany(
+                    'INSERT OR IGNORE INTO blocks '
+                    'SELECT ?,?,?,?,?,?,?,?,? WHERE NOT EXISTS ('
+                    '  SELECT 1 FROM watermarks w WHERE w.run_key=?1 '
+                    '  AND w.job=?2 AND w.worker_id=?3 '
+                    '  AND w.max_block_id>=?4)',
+                    rows)
+                self._conn.commit()
+            return cur.rowcount if cur.rowcount >= 0 else len(rows)
 
     def _quota(self, run_key: str) -> int:
         with self._lock:

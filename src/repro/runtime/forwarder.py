@@ -29,6 +29,7 @@ from repro.runtime.database import ResultDatabase
 from repro.runtime.packets import (BLOCKS, PacketError, decode_blocks,
                                    encode_blocks, frame, unframe)
 from repro.runtime.reservoir import WalkerReservoir
+from repro.tracing import span
 
 
 class Forwarder:
@@ -101,18 +102,19 @@ class Forwarder:
 
     # -- egress -------------------------------------------------------------
     def _push_up(self, blocks: list[BlockResult]) -> None:
-        if self.db is not None:                      # root: store directly
-            self.db.append(blocks)
-            return
-        # the paper's compressed transfer, as a CRC-framed binary packet
-        payload = frame(BLOCKS, encode_blocks(blocks))
-        self.packets_sent += 1
-        self.bytes_sent += len(payload)
-        for anc in self.ancestors:                   # parent, then fallbacks
-            if anc.alive and anc.submit_packet(payload):
+        with span('qmc.forward'):
+            if self.db is not None:                  # root: store directly
+                self.db.append(blocks)
                 return
-        # no live ancestor: blocks are dropped — the unbiasedness contract
-        # makes this safe (they were never counted).
+            # the paper's compressed transfer, as a CRC-framed binary packet
+            payload = frame(BLOCKS, encode_blocks(blocks))
+            self.packets_sent += 1
+            self.bytes_sent += len(payload)
+            for anc in self.ancestors:               # parent, then fallbacks
+                if anc.alive and anc.submit_packet(payload):
+                    return
+            # no live ancestor: blocks are dropped — the unbiasedness
+            # contract makes this safe (they were never counted).
 
     def _push_walkers_up(self) -> None:
         w, e = self.reservoir.state()

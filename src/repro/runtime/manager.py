@@ -33,6 +33,7 @@ from repro.runtime.blocks import RunningAverage
 from repro.runtime.database import ResultDatabase
 from repro.runtime.forwarder import Forwarder, build_tree
 from repro.runtime.worker import Sampler
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,14 +178,15 @@ class QMCManager:
                 w.send_params(version, vec)
 
     def poll(self) -> RunningAverage:
-        self.backend.tick(self)
-        avg = self.db.running_average(self.run_key)
-        if (self.control.e_trial_feedback and avg.n_blocks > 0
-                and np.isfinite(avg.energy)):
-            for w in self.workers:
-                if w.running:
-                    w.send_e_trial(avg.energy)
-        return avg
+        with span('qmc.poll'):
+            self.backend.tick(self)
+            avg = self.db.running_average(self.run_key)
+            if (self.control.e_trial_feedback and avg.n_blocks > 0
+                    and np.isfinite(avg.energy)):
+                for w in self.workers:
+                    if w.running:
+                        w.send_e_trial(avg.energy)
+            return avg
 
     def run(self) -> RunningAverage:
         """Blocking run to completion. Returns the final running average."""

@@ -28,6 +28,7 @@ import jax
 
 from repro.core.driver import EnsembleDriver
 from repro.runtime.blocks import BlockAccumulator
+from repro.tracing import span
 
 
 class BlockSampler:
@@ -70,18 +71,28 @@ class BlockSampler:
         self.params_version = int(version)
 
     def run_subblock(self, state, step: int):
-        wkey, st = state
-        pv = self.params_version       # read version BEFORE params (see
-        params = self.params           # apply_params ordering contract)
-        _, k_blocks = jax.random.split(wkey)
-        key = jax.random.fold_in(k_blocks, step)
-        st, stats = self.driver.run_block(params, st, key)
-        ens = st.ens if hasattr(st, 'ens') else st
-        acc = BlockAccumulator.from_stats(stats)
-        if getattr(self.propagator, 'n_opt', 0):
-            # host-side parameter-version stamp: rides the weighted-mean
-            # merge, so sub-blocks merged across a version change average
-            # to a fractional stamp and are rejected downstream
-            acc = dataclasses.replace(acc,
-                                      aux={**acc.aux, 'opt_pv': float(pv)})
-        return ((wkey, st), acc, np.asarray(ens.r), np.asarray(ens.e_loc))
+        """One jit'd sub-block -> (state, BlockAccumulator, walkers,
+        energies), host copies last.  Host spans: ``qmc.subblock`` around
+        the call, ``qmc.dispatch`` (jit lookup and enqueue, or compile) and
+        ``qmc.fetch`` (the host copies, opened once the device is done, so
+        a profile started during the block still names the copies)."""
+        with span('qmc.subblock'):
+            wkey, st = state
+            pv = self.params_version       # read version BEFORE params (see
+            params = self.params           # apply_params ordering contract)
+            with span('qmc.dispatch'):
+                _, k_blocks = jax.random.split(wkey)
+                key = jax.random.fold_in(k_blocks, step)
+                st, stats = self.driver.run_block(params, st, key)
+            ens = st.ens if hasattr(st, 'ens') else st
+            jax.block_until_ready((stats, ens.r, ens.e_loc))
+            with span('qmc.fetch'):
+                acc = BlockAccumulator.from_stats(stats)
+                r, e_loc = np.asarray(ens.r), np.asarray(ens.e_loc)
+            if getattr(self.propagator, 'n_opt', 0):
+                # host-side parameter-version stamp: rides the weighted-mean
+                # merge, so sub-blocks merged across a version change average
+                # to a fractional stamp and are rejected downstream
+                acc = dataclasses.replace(
+                    acc, aux={**acc.aux, 'opt_pv': float(pv)})
+            return (wkey, st), acc, r, e_loc

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.runtime.blocks import BlockAccumulator, BlockResult
 from repro.runtime.forwarder import Forwarder
+from repro.tracing import span
 
 
 class Sampler(Protocol):
@@ -97,15 +98,17 @@ class Worker:
             step = 0
             while not self._stop.is_set() and not self._crash.is_set():
                 if self.e_trial_update is not None:
-                    state = self.sampler.set_e_trial(state,
-                                                     self.e_trial_update)
+                    with span('qmc.feedback'):
+                        state = self.sampler.set_e_trial(
+                            state, self.e_trial_update)
                     self.e_trial_update = None
                 if self.params_update is not None:
                     version, vec = self.params_update
                     self.params_update = None
                     apply = getattr(self.sampler, 'apply_params', None)
                     if apply is not None:
-                        apply(version, vec)
+                        with span('qmc.feedback'):
+                            apply(version, vec)
                 acc = BlockAccumulator()
                 walkers = energies = None
                 for _ in range(self.subblocks_per_block):
@@ -114,16 +117,18 @@ class Worker:
                     state, sub, walkers, energies = \
                         self.sampler.run_subblock(state, step)
                     step += 1
-                    acc = acc.merge(sub)           # the one weighted-merge
+                    with span('qmc.merge'):
+                        acc = acc.merge(sub)       # the one weighted-merge
                     if self._stop.is_set():
                         break                      # truncated block: flush
                 if acc.is_valid():
-                    blk = acc.to_block(self.run_key, self.worker_id,
-                                       self.blocks_done, job=self.job)
-                    self.forwarder.submit_blocks([blk])
-                    if walkers is not None:
-                        self.forwarder.submit_walkers(
-                            np.asarray(walkers), np.asarray(energies))
+                    with span('qmc.submit'):
+                        blk = acc.to_block(self.run_key, self.worker_id,
+                                           self.blocks_done, job=self.job)
+                        self.forwarder.submit_blocks([blk])
+                        if walkers is not None:
+                            self.forwarder.submit_walkers(
+                                np.asarray(walkers), np.asarray(energies))
                     self.blocks_done += 1
         except Exception:                           # pragma: no cover
             self.error = traceback.format_exc()

@@ -51,7 +51,8 @@ def test_driver_dmc_block_stats_contract(h2_wf):
                          steps=8, donate=False)
     _, stats = drv.run_block(params, state, jax.random.PRNGKey(1))
     assert np.isfinite(float(stats.e_mean))
-    assert set(stats.aux) == {'accept', 'pop_weight', 'sign_flips'}
+    assert set(stats.aux) == {'accept', 'pop_weight', 'sign_flips',
+                              'ao_fill'}
 
 
 def test_make_propagator_registry(h2_wf):

@@ -96,6 +96,16 @@ def test_population_is_constant_through_dmc():
     assert st2.ens.r.shape == ens.r.shape                   # constant M
 
 
+def test_dmc_block_reports_ao_fill():
+    """DMC's aux carries the active AOs per electron, as VMC's does."""
+    cfg, params = build_wavefunction(*h2())
+    st = init_dmc(init_walkers(cfg, params, jax.random.PRNGKey(0), 16),
+                  e_trial=-1.1)
+    _, stats = dmc_driver(cfg, steps=4, tau=0.02).run_block(
+        params, st, jax.random.PRNGKey(1))
+    assert 0.0 < float(stats.aux['ao_fill']) <= cfg.basis.n_ao
+
+
 def test_blocks_are_reproducible():
     """Same key => bitwise-identical block stats (determinism contract)."""
     cfg, params = build_wavefunction(*h2())
