@@ -298,7 +298,10 @@ def active_ao_indices(basis: BasisSet, atom_active: jnp.ndarray, k_max: int,
 
     Args:
       atom_active: (n_e, n_atoms) bool.
-      k_max: pad/truncate length (multiple of 128 for the TPU kernel).
+      k_max: pad/truncate length (multiple of 128 for the TPU kernel).  The
+        wavefunction builds these lists only where ``0 < k_max < n_ao``:
+        at ``k_max >= n_ao`` it runs the dense product instead
+        (``wavefunction._product_route``).
       ao_mask: optional precomputed ``atom_active[:, ao_atom]`` (n_e, n_ao)
         — callers that already expanded the atom mask (sparsity stats) pass
         it to skip the second gather.  Every per-sweep caller does; the

@@ -7,6 +7,10 @@ Three implementations, all returning ``C: (n_orb, n_elec, 5)``:
 * ``mo_products_sparse`` — the paper's algorithm, vectorized: per-electron
   gather of the active columns of A (A stays DENSE — the paper's key choice)
   against the packed B rows.  O(n_orb * n_elec * K) with K ~ const in N.
+  The wavefunction runs it only where packing shortens the contraction,
+  ``k_max < n_ao``; at ``k_max >= n_ao`` the dense product computes the same
+  C (B is an exact zero outside each atom's radius) without the index build
+  and the gather (``wavefunction._product_route``).
 * ``kernels.sparse_mo.ops.sparse_mo_products`` — the Pallas TPU kernel with
   (8·k,128) tile blocking; bit-compared against these in tests.
 

@@ -7,6 +7,9 @@ The computational pipeline per walker (paper §II.C / §III):
 
 ``method`` selects the product implementation: 'dense' (O(N^3) oracle),
 'sparse' (paper's algorithm, gather form), 'kernel' (Pallas tile-sparse).
+'sparse' packs the active AOs only where that shortens the contraction,
+``0 < k_max < n_ao``; otherwise it runs the dense product
+(``_product_route``).
 """
 from __future__ import annotations
 
@@ -30,7 +33,10 @@ class WavefunctionConfig:
     basis: BasisSet
     n_up: int
     n_dn: int
-    k_max: int = 0                 # padded active-AO count; 0 -> n_ao (dense)
+    k_max: int = 0                 # padded active-AO count of the packed
+    #                                'sparse' product, which runs only for
+    #                                0 < k_max < n_ao; 0 or k_max >= n_ao
+    #                                -> the dense product (nothing to pack)
     shared_orbitals: bool = True   # closed-shell: one MO block for both spins
     method: str = 'sparse'         # 'dense' | 'sparse' | 'kernel' |
     #                                'fused' | 'fused-kernel' (the latter
@@ -154,6 +160,33 @@ def _mo_product_method(cfg: WavefunctionConfig) -> str:
     return cfg.method
 
 
+# trace-time counts of the product route the unscreened, non-kernel MO
+# tensor passes took (tests assert which route a configuration engages)
+_ROUTE_COUNTS = {'dense': 0, 'packed': 0}
+
+
+def product_route_counts() -> dict:
+    """Traces that took the dense and the packed product route."""
+    return dict(_ROUTE_COUNTS)
+
+
+def _product_route(cfg: WavefunctionConfig) -> str:
+    """'packed' or 'dense': the product of an unscreened, non-kernel pass.
+
+    Packing each electron's active AOs into ``k_max`` slots shortens the
+    contraction only where ``k_max < n_ao``.  At ``k_max >= n_ao`` nothing
+    is ever truncated, and since B is an exact zero outside each atom's
+    radius (``aos.eval_ao_block``) the packed C is the dense C up to the
+    order of the fp32 additions; the dense product then takes it without
+    the index build and the gather.  Reads static shapes only.
+    """
+    packed = (_mo_product_method(cfg) != 'dense'
+              and 0 < cfg.k_max < cfg.basis.n_ao)
+    route = 'packed' if packed else 'dense'
+    _ROUTE_COUNTS[route] += 1
+    return route
+
+
 def _mo_tensor_screened(cfg: WavefunctionConfig,
                         params: WavefunctionParams, r_elec: jnp.ndarray,
                         chunk: int = 0):
@@ -208,7 +241,7 @@ def _mo_tensor(cfg: WavefunctionConfig, params: WavefunctionParams,
         with jax.named_scope('qmc.mo_product'):
             return sparse_mo_products(params.mo, B, ao_mask, tile_o=to,
                                       tile_k=tk, tile_e=te), count
-    if _mo_product_method(cfg) == 'dense' or cfg.k_max <= 0:
+    if _product_route(cfg) == 'dense':
         with jax.named_scope('qmc.mo_product'):
             return mos.mo_products_dense(params.mo, B), count
     with jax.named_scope('qmc.ao_index'):
@@ -230,6 +263,7 @@ def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
     profits from:
 
       * dense  — one batched GEMM against the shared A (no layout change);
+        'sparse' takes it too wherever ``k_max >= n_ao`` (``_product_route``);
       * sparse — per-electron gather flattened walker-major, so the scan's
         gathered-A working set stays cache-sized instead of growing by W
         (per-walker vmap multiplies the per-chunk gather by W);
@@ -262,7 +296,7 @@ def _mo_tensor_ensemble(cfg: WavefunctionConfig, params: WavefunctionParams,
                                    ao_mask.reshape(W * n_e, -1),
                                    tile_o=to, tile_k=tk, tile_e=te)
             return jnp.moveaxis(C.reshape(n_rows, W, n_e, 5), 1, 0), count
-    if _mo_product_method(cfg) == 'dense' or cfg.k_max <= 0:
+    if _product_route(cfg) == 'dense':
         with jax.named_scope('qmc.mo_product'):
             Cw = jnp.einsum('oa,waec->woec', params.mo, Bw,
                             precision=jax.lax.Precision.HIGHEST,

@@ -94,13 +94,15 @@ def test_inactive_values_cannot_leak():
 
 def test_kernel_on_real_screening_structure():
     """End to end on a bench system: the kernel front door reproduces the
-    unscreened sparse MO tensor (eps = 0 structure)."""
+    unscreened sparse MO tensor (eps = 0 structure).  The 80-electron
+    system has 172 AOs, so ``k_max`` 160 runs the packed product, and no
+    electron has more than 160 active AOs, so it truncates nothing."""
     from repro.core import wavefunction as wf
     from repro.core.screening import active_ao_lists
     from repro.core import aos
     from repro.systems.bench import build_bench_wavefunction, \
         make_bench_system
-    s = make_bench_system('micro-peptide', n_elec=60, seed=5)
+    s = make_bench_system('micro-peptide', n_elec=80, seed=5)
     cfg_d, params = build_bench_wavefunction(s, method='sparse', k_max=160)
     cfg_k, _ = build_bench_wavefunction(s, method='kernel', k_max=160,
                                         screen_eps=0.0)
@@ -109,7 +111,11 @@ def test_kernel_on_real_screening_structure():
     r = jnp.asarray(s.mol.coords[at]
                     + rng.normal(scale=1.2, size=(s.mol.n_elec, 3)),
                     jnp.float32)
-    C_d, _ = wf._mo_tensor(cfg_d, params, r)
+    assert cfg_d.k_max < cfg_d.basis.n_ao
+    packed = wf.product_route_counts()['packed']
+    C_d, count = wf._mo_tensor(cfg_d, params, r)
+    assert wf.product_route_counts()['packed'] == packed + 1
+    assert int(jnp.max(count)) <= cfg_d.k_max
     idx, active, _ = active_ao_lists(cfg_k.screening, r)
     Bp = aos.eval_ao_block_screened(cfg_k.basis, params.coords, r, idx,
                                     active)

@@ -175,7 +175,11 @@ def test_ao_cutoff_radii_monotone_in_eps():
 # ---------------------------------------------------------------------------
 # eps = 0: bitwise-identical physics across every evaluation surface
 # ---------------------------------------------------------------------------
-def _pair(n_elec=60, eps=0.0, method='sparse'):
+def _pair(n_elec=80, eps=0.0, method='sparse'):
+    """Unscreened and screened configs of one system.  The unscreened side
+    runs the packed product without truncating: the 80-electron system
+    has 172 AOs, more than ``k_max`` 160, and at most 144 active at any
+    point of a grid over the molecule (``_assert_packs_untruncated``)."""
     s = _system(n_elec)
     cfg_d, params = build_bench_wavefunction(s, method=method, k_max=160)
     cfg_s, _ = build_bench_wavefunction(s, method=method, k_max=160,
@@ -183,9 +187,19 @@ def _pair(n_elec=60, eps=0.0, method='sparse'):
     return s, cfg_d, cfg_s, params
 
 
+def _assert_packs_untruncated(cfg, params, r):
+    """The packed route runs (``k_max < n_ao``) and truncates nothing at
+    these positions (every active count fits ``k_max``)."""
+    assert cfg.k_max < cfg.basis.n_ao
+    _, atom_active = aos.eval_ao_block(cfg.basis, params.coords, r)
+    count = aos.atom_to_ao(atom_active, cfg.basis.ao_atom).sum(-1)
+    assert int(jnp.max(count)) <= cfg.k_max
+
+
 def test_eps0_psi_state_bitwise():
     s, cfg_d, cfg_s, params = _pair()
     r = _positions(s, seed=2)
+    _assert_packs_untruncated(cfg_d, params, r)
     a = wf.psi_state(cfg_d, params, r)
     b = wf.psi_state(cfg_s, params, r)
     for field in ('log_psi', 'drift', 'e_loc', 'e_kin', 'e_pot'):
@@ -198,6 +212,7 @@ def test_eps0_psi_state_bitwise():
 def test_eps0_psi_state_batched_bitwise():
     s, cfg_d, cfg_s, params = _pair()
     R = jnp.stack([_positions(s, seed=i) for i in range(4)])
+    _assert_packs_untruncated(cfg_d, params, R)
     a = wf.psi_state_batched(cfg_d, params, R)
     b = wf.psi_state_batched(cfg_s, params, R)
     for field in ('log_psi', 'drift', 'e_loc'):
@@ -252,6 +267,7 @@ def test_exhaustive_routes_to_unscreened_branch_bitwise():
     assert cfg_x.screening is not None and cfg_x.screening.exhaustive
     assert not wf._screening_active(cfg_x)
     r = _positions(s, seed=4)
+    _assert_packs_untruncated(cfg_d, params, r)
     a = wf.psi_state(cfg_d, params, r)
     b = wf.psi_state(cfg_x, params, r)
     np.testing.assert_array_equal(np.asarray(a.log_psi),
@@ -282,16 +298,19 @@ def test_sparse_pipeline_never_rebuilds_ao_mask():
     rebuild (aos.mask_fallback_count) must not fire."""
     from repro.core.driver import Population
     from repro.core.sem import SEMVMCPropagator
-    s = _system()
+    s = _system(80)
     cfg, params = build_bench_wavefunction(s, method='sparse', k_max=160)
     before = aos.mask_fallback_count()
+    packed = wf.product_route_counts()['packed']
     r = _positions(s, seed=6)
+    _assert_packs_untruncated(cfg, params, r)
     wf.psi_state(cfg, params, r)
     wf.psi_state_batched(cfg, params, jnp.stack([r, r]))
     prop = SEMVMCPropagator(cfg, step_size=0.4)
     state = prop.init(params, jax.random.PRNGKey(0), 2)
     prop.propagate(params, state, jax.random.PRNGKey(1), Population())
     assert aos.mask_fallback_count() == before
+    assert wf.product_route_counts()['packed'] >= packed + 3
     # the instrumented fallback still exists for direct API callers
     B, atom_active = aos.eval_ao_block(cfg.basis, params.coords, r)
     aos.active_ao_indices(cfg.basis, atom_active, cfg.k_max)

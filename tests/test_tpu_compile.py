@@ -152,12 +152,10 @@ def test_plan_interprets_off_tpu_and_compiles_on_tpu(kernel, tiles, dims):
     assert kernel_plan(kernel, tiles, **dims).interpret
 
 
-def test_mo_tensor_pass_has_no_walker_batched_gather(one_chip):
-    """The ensemble MO tensor pass at `smallest` with 64 walkers compiles
-    for a v5e without gathering a walker-batched operand by constant
-    indices: that gather (the AO power table, ``aos._monomial_1d``) halted
-    the chip.  Only the sparse product's data-dependent gathers remain."""
-    import re
+@pytest.fixture(scope='module')
+def smallest_mo_pass(one_chip):
+    """HLO text of the ensemble MO tensor pass at `smallest` with 64
+    walkers, compiled for a v5e."""
     from repro.core.wavefunction import _mo_tensor_ensemble
     from repro.systems import build_system
     cfg, params = build_system('smallest')
@@ -165,10 +163,33 @@ def test_mo_tensor_pass_has_no_walker_batched_gather(one_chip):
                                                      sharding=one_chip),
                       params)
     R = jax.ShapeDtypeStruct((W, cfg.n_elec, 3), F32, sharding=one_chip)
-    text = jax.jit(lambda p, r: _mo_tensor_ensemble(cfg, p, r)[0]).lower(
+    return cfg, jax.jit(lambda p, r: _mo_tensor_ensemble(cfg, p, r)[0]).lower(
         ps, R).compile().as_text()
+
+
+def test_mo_tensor_pass_has_no_walker_batched_gather(smallest_mo_pass):
+    """The ensemble MO tensor pass at `smallest` with 64 walkers compiles
+    for a v5e without gathering a walker-batched operand by constant
+    indices: that gather (the AO power table, ``aos._monomial_1d``) halted
+    the chip.  Only the packed product's data-dependent gathers may
+    remain, where it runs."""
+    import re
+    _, text = smallest_mo_pass
     batched = re.findall(rf'gather\(.*slice_sizes={{{W},', text)
     assert not batched, batched[:2]
+
+
+def test_mo_tensor_pass_runs_the_dense_product(smallest_mo_pass):
+    """At `smallest` ``k_max`` (512) is at least ``n_ao`` (346), so the pass
+    takes the dense product: no active-AO index build (its sorts and
+    scatters) and no ``qmc.ao_index`` scope in the compiled program."""
+    import re
+    cfg, text = smallest_mo_pass
+    assert cfg.k_max >= cfg.basis.n_ao
+    ops = set(re.findall(r'= \S+ ([\w-]+)\(', text))
+    assert not ops & {'sort', 'scatter'}, ops & {'sort', 'scatter'}
+    assert 'qmc.ao_index' not in text
+    assert 'qmc.mo_product' in text
 
 
 def test_plan_tpu_tiles_are_aligned():

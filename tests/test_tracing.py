@@ -49,7 +49,10 @@ def compiled_blocks(tmp_path_factory):
     was = {name: getattr(jax.config, name) for name in _CACHE_SETTINGS}
     enable_compile_cache(tmp_path_factory.mktemp('jax_cache'))
     try:
-        cfg, params = build_wavefunction(*water(), method='sparse', k_max=8)
+        # k_max below water's 7 AOs, so the packed product (and its
+        # qmc.ao_index layer) is in the block
+        cfg, params = build_wavefunction(*water(), method='sparse', k_max=6)
+        assert cfg.k_max < cfg.basis.n_ao
         out = {}
         for method in EXPECTED:
             drv = EnsembleDriver(make_propagator(method, cfg, tau=0.1),
